@@ -1,20 +1,17 @@
-"""Kernel microbenchmarks + per-kernel roofline table.
+"""Kernel microbenchmarks + per-kernel roofline table, on a TPU only.
 
-Timing runs wherever the host is (interpret mode on CPU — correctness-path
-timing; compiled kernels on TPU).  Each fused kernel additionally gets a
-ROOFLINE row: the ideal HBM byte / MXU flop model from
-``repro.analysis.roofline`` gives a memory- (or compute-) bound floor
-time, and ``achieved_fraction`` = floor / measured.  The fraction is
-gated: on TPU the kernels must reach a minimum fraction of the
-memory-bound peak; under the CPU interpreter the fraction is a tiny
-consistency number and the gate only checks the model produced sane
-positive terms.  Rows cover the decode kernel per (k, layout) — the paged
-layout per page bucket — and the bulk-chunk prefill kernel, matching the
-serve engine's dispatch grid.
+Times the compiled kernels and gives each fused kernel a ROOFLINE row:
+the ideal HBM byte / MXU flop model from ``repro.analysis.roofline`` and
+the peaks of the device's ``device_kind`` give a memory- (or compute-)
+bound floor time, and ``achieved_fraction`` = floor / measured is gated
+against ``MIN_FRACTION``.  Rows cover the decode kernel
+per (k, layout) — the paged layout per page bucket — and the bulk-chunk
+prefill kernel.  With no TPU the benchmark refuses to run: a timing of
+the Pallas interpreter says nothing about the chip.
 
 CLI: ``python -m benchmarks.bench_kernels [--smoke]`` — smoke shrinks
-shapes/iters for CI (both JAX pins run it; ``BENCH_kernels.json`` lands in
-``$REPRO_BENCH_OUT`` with the roofline table under ``extra.roofline``).
+shapes/iters (``BENCH_kernels.json`` lands in ``$REPRO_BENCH_OUT`` with
+the roofline table under ``extra.roofline``).
 """
 from __future__ import annotations
 
@@ -35,14 +32,25 @@ from repro.kernels.swan_prune.ops import swan_prune
 from repro.core.projections import random_orthogonal
 from benchmarks.common import bench_record, emit, gate, timeit_call
 
-# minimum achieved-fraction-of-peak per backend: on TPU the fused kernels
-# are memory-bound streams and must hit a substantial fraction of HBM
-# peak; the CPU interpreter executes the kernel body in Python, so the
-# gate only requires the model terms to be finite and positive
-MIN_FRACTION = {"tpu": 0.4}
+# minimum achieved fraction of the roofline: the fused kernels are
+# memory-bound streams and must hit a substantial fraction of HBM peak
+MIN_FRACTION = 0.4
 
 
-def _emit_roofline(rec, row) -> float:
+def _device_kind() -> str:
+    """The TPU's device kind; refuses another platform, and (through
+    ``roofline.peaks``) a device with no published peaks."""
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise RuntimeError(f"bench_kernels times compiled kernels and needs "
+                           f"a TPU; found platform {dev.platform!r}")
+    rl.peaks(dev.device_kind)
+    return dev.device_kind
+
+
+def _emit_roofline(rec, name, us, hbm_bytes, **tags) -> float:
+    row = rl.roofline_row(name, us, hbm_bytes,
+                          device_kind=rec.extra["device_kind"], **tags)
     rec.extra.setdefault("roofline", []).append(row)
     emit(f"roofline_{row['name']}", row["us_per_call"],
          f"bytes={row['hbm_bytes']}_floor_us={row['floor_us']:.2f}"
@@ -73,9 +81,9 @@ def _decode_rooflines(rec, cfg, smoke: bool):
             q, cache, swan, cfg, pos, block_s=64), iters=iters, warmup=warmup)
         nb = rl.swan_decode_kernel_bytes(B=B, Kv=Kv, G=G, dh=dh, S=S,
                                          k_max=k, buffer=bt, quantized=False)
-        fracs.append(_emit_roofline(rec, rl.roofline_row(
-            f"swan_decode_slab_k{k}", us, nb, kernel="swan_decode",
-            layout="slab", k=k)))
+        fracs.append(_emit_roofline(
+            rec, f"swan_decode_slab_k{k}", us, nb, kernel="swan_decode",
+            layout="slab", k=k))
         for pb in buckets:
             n_pages = B * pb + 1
             pool_side = {
@@ -102,10 +110,10 @@ def _decode_rooflines(rec, cfg, smoke: bool):
             nb = rl.swan_decode_kernel_bytes(B=B, Kv=Kv, G=G, dh=dh,
                                              S=pb * ps, k_max=k, buffer=bt,
                                              quantized=False)
-            fracs.append(_emit_roofline(rec, rl.roofline_row(
-                f"swan_decode_paged_k{k}_pg{pb}", us, nb,
+            fracs.append(_emit_roofline(
+                rec, f"swan_decode_paged_k{k}_pg{pb}", us, nb,
                 kernel="swan_decode_paged", layout="paged", k=k,
-                page_bucket=pb, page_size=ps)))
+                page_bucket=pb, page_size=ps))
     return fracs
 
 
@@ -127,9 +135,9 @@ def _chunk_roofline(rec, cfg, smoke: bool):
                      iters=iters, warmup=warmup)
     nb = rl.swan_chunk_kernel_bytes(B=B, Kv=Kv, Q=Q, dh=dh, S=S, k_max=k,
                                     quantized=False)
-    return [_emit_roofline(rec, rl.roofline_row(
-        f"swan_chunk_stats_S{S}_k{k}", us, nb, kernel="swan_chunk_stats",
-        layout="slab", k=k))]
+    return [_emit_roofline(
+        rec, f"swan_chunk_stats_S{S}_k{k}", us, nb, kernel="swan_chunk_stats",
+        layout="slab", k=k)]
 
 
 def _flash_roofline(rec, smoke: bool):
@@ -143,9 +151,9 @@ def _flash_roofline(rec, smoke: bool):
                      iters=iters, warmup=warmup)
     nb = rl.flash_kernel_bytes(B=1, H=4, Sq=Sq, Sk=Sq, dh=32)
     fl = rl.flash_kernel_flops(B=1, H=4, Sq=Sq, Sk=Sq, dh=32)
-    return [_emit_roofline(rec, rl.roofline_row(
-        f"flash_prefill_Sq{Sq}", us, nb, flops=fl, kernel="flash_prefill",
-        layout="dense"))]
+    return [_emit_roofline(
+        rec, f"flash_prefill_Sq{Sq}", us, nb, flops=fl, kernel="flash_prefill",
+        layout="dense")]
 
 
 def _legacy_paths(cfg, smoke: bool) -> None:
@@ -183,7 +191,7 @@ def _legacy_paths(cfg, smoke: bool) -> None:
     x = jax.random.normal(key, (2, 2, 128, 32), jnp.float32)
     P = random_orthogonal(jax.random.fold_in(key, 5), (2,), 32)
     us = timeit_call(lambda: swan_prune(x, P, 8, tile=64), iters=3, warmup=1)
-    emit("swan_prune_pallas_interpret", us, "T=128_dh=32_k=8")
+    emit("swan_prune_pallas", us, "T=128_dh=32_k=8")
 
     from repro.core.winnow import topk_pack, rotate_k
     prune_ref = jax.jit(
@@ -199,18 +207,18 @@ def _run(rec, smoke: bool) -> None:
     fracs += _decode_rooflines(rec, cfg, smoke)
     fracs += _chunk_roofline(rec, cfg, smoke)
     fracs += _flash_roofline(rec, smoke)
-    backend = jax.default_backend()
-    floor = MIN_FRACTION.get(backend, 0.0)
     worst = min(fracs)
-    gate("kernels_roofline_fraction", worst > floor,
-         f"backend={backend}: worst achieved fraction {worst:.3e} must "
-         f"exceed {floor} over {len(fracs)} kernel rows")
+    gate("kernels_roofline_fraction", worst > MIN_FRACTION,
+         f"{rec.extra['device_kind']}: worst achieved fraction {worst:.3e} "
+         f"must exceed {MIN_FRACTION} over {len(fracs)} kernel rows")
     _legacy_paths(cfg, smoke)
 
 
 def run(smoke: bool = False) -> None:
+    kind = _device_kind()
     with bench_record("kernels") as rec:
         rec.extra["smoke"] = smoke
+        rec.extra["device_kind"] = kind
         _run(rec, smoke)
 
 

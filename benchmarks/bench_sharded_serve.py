@@ -1,5 +1,7 @@
-"""Mesh-sharded serve engine under the Poisson trace: token identity and
-dispatch-count independence from shard count.
+"""CPU identity harness for the mesh-sharded serve engine: token identity
+and dispatch-count independence from shard count, on 8 simulated host
+devices.  It measures no device, and refuses to run where a TPU is
+present (four real chips are ``chip_smoke.py --four-chips``).
 
 The sharded engine partitions the batched serve state — slab/ring leaves,
 per-sequence pos/k, and the paged pool's page axis — over a ``data`` mesh
@@ -22,12 +24,11 @@ simulated host mesh, and gates:
   * the sharded engine drains the trace in the same number of engine
     steps (same decode throughput in scheduler time).
 
-Wall-clock per-step latency is reported for color (not gated — 8 host
-devices on one CPU SERIALIZE the per-shard compute; the win is HBM/FLOP
-scale-out on real meshes).  ``--smoke`` shrinks the trace for CI
-(exercised on both the JAX floor and current pins under
-XLA_FLAGS=--xla_force_host_platform_device_count=8 — see
-.github/workflows/ci.yml).
+Host wall-clock per step is printed as a CPU number (not gated, and not a
+device metric — 8 host devices on one CPU serialize the per-shard
+compute).  ``--smoke`` shrinks the trace for CI (run with
+``JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8``
+— see .github/workflows/ci.yml).
 """
 from __future__ import annotations
 
@@ -39,7 +40,6 @@ if "--xla_force_host_platform_device_count" not in os.environ.get(
         "XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                + " --xla_force_host_platform_device_count=8")
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import argparse
 import time
@@ -56,7 +56,7 @@ from repro.models import get_model
 from repro.runtime.serve_engine import Request, ServeEngine
 from repro.runtime.serve_loop import calibrate_swan
 
-DP = 8               # shards on the simulated host mesh
+DP = 8              # shards on the simulated host mesh
 N_SLOTS = 16         # 2 slots per shard
 MAX_SEQ = 128
 CHUNK = 8
@@ -212,6 +212,10 @@ def _run(smoke: bool = False) -> None:
 
 
 def run(smoke: bool = False) -> None:
+    if any(d.platform == "tpu" for d in jax.devices()):
+        raise RuntimeError("bench_sharded_serve is a CPU identity harness; "
+                           "this machine has a TPU — run chip_smoke.py "
+                           "--four-chips")
     with bench_record("sharded_serve"):
         _run(smoke=smoke)
 

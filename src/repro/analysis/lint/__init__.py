@@ -4,10 +4,10 @@ Two layers:
 
 * **Layer 1 (``repro.analysis.lint.rules``)** — stdlib-``ast`` rules
   that machine-check the ROADMAP standing constraints at review time:
-  JAX-floor compat (SWAN101), no host syncs on the serve hot path
-  (SWAN102), power-of-two shape bucketing in dispatch builders
-  (SWAN103), sharding-spec completeness for serve-state leaves
-  (SWAN104), and MetricsRegistry-only observability (SWAN105).
+  no host syncs on the serve hot path (SWAN102), power-of-two shape
+  bucketing in dispatch builders (SWAN103), sharding-spec completeness
+  for serve-state leaves (SWAN104), and MetricsRegistry-only
+  observability (SWAN105).
   Dependency-free: no jax import, runs anywhere.
 * **Layer 2 (``repro.analysis.lint.audit``)** — a compiled-artifact
   auditor that lowers the engine's chunk/decode executables for a
@@ -15,8 +15,8 @@ Two layers:
   ``repro.analysis.hlo``, and asserts the perf contract: bounded
   executable counts (one per step shape), zero host transfers inside
   dispatch bodies, an empty collective inventory (the serve path is
-  lane-local by contract), and Pallas grid/VMEM prechecks for the
-  ``swan_decode`` / ``flash_prefill`` kernels.
+  lane-local by contract), and Pallas grid/block-layout prechecks for
+  the ``swan_decode`` / ``swan_chunk`` / ``flash_prefill`` kernels.
 
 CLI: ``python -m repro.analysis.lint [--check] [--audit-smoke] ...`` —
 see ``docs/static_analysis.md`` for the rule catalogue, suppression

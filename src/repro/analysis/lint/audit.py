@@ -27,9 +27,9 @@ AOT-lowers the ServeEngine's chunk/decode executables over a
   (iii) collective inventory matches the sharding contract — the serve
         path is lane-local by design (shard_map bodies never
         communicate), so the per-collective census must be EMPTY;
-  (iv)  Pallas kernel prechecks — grid divisibility and VMEM footprint
-        vs the per-core budget for ``swan_decode`` and ``flash_prefill``
-        at the engine's shapes.
+  (iv)  Pallas kernel prechecks — grid divisibility and the TPU
+        compiler's block-layout rules for the SWAN kernels (and the
+        ``flash_prefill`` VMEM model) at the engine's shapes.
 
 Each assertion is an ``AuditCheck`` with status pass/fail/skip; the CLI
 folds them into the JSON report next to the Layer 1 findings.  The check
@@ -162,47 +162,34 @@ def logical_view_check(hlo_text: str, label: str, view_elems: int,
 
 
 def kernel_precheck_checks(cfg, swan, max_seq: int,
-                           page_size: Optional[int] = None,
-                           chunk_q: Optional[int] = None) -> List[AuditCheck]:
-    """(iv): static Pallas grid/VMEM validation at the engine's shapes.
-    ``page_size`` adds the paged-tile grid (sequence blocks = page-sized
-    pool tiles gathered via scalar prefetch); ``chunk_q`` adds the
-    bulk-chunk prefill stats kernel at that query-row count."""
+                           page_size: Optional[int] = None
+                           ) -> List[AuditCheck]:
+    """(iv): static Pallas grid and block-layout validation at the engine's
+    shapes.  The SWAN decode and chunk-stats kernels share their tile and
+    scale BlockSpecs, so one check covers both; ``page_size`` adds the
+    paged-tile grid (sequence blocks = page-sized pool tiles gathered via
+    scalar prefetch).  VMEM is the TPU compile's call
+    (``tests/test_tpu_compile.py``)."""
     from repro.kernels.flash_prefill import flash_prefill as fp
-    from repro.kernels.flash_prefill import swan_chunk as sc
     from repro.kernels.swan_decode import swan_decode as sd
 
     def fold(name: str, r: dict) -> AuditCheck:
         status = "fail" if r["errors"] else "pass"
-        detail = "; ".join(r["errors"] + r["warnings"]) or \
-            f"vmem {r['vmem_bytes']} B"
+        detail = "; ".join(r["errors"] + r["warnings"]) or "block rules hold"
         return AuditCheck(f"pallas-precheck/{name}", status, detail)
 
     out: List[AuditCheck] = []
     if swan is not None:
-        quant = getattr(swan, "quantize", False)
-        G = cfg.n_heads // cfg.n_kv_heads
-        out.append(fold("swan_decode", sd.precheck(
-            B=1, Kv=cfg.n_kv_heads, G=G, dh=cfg.d_head, S=max(max_seq, 1),
-            k_max=swan.k_max, b=swan.buffer, quantized=quant)))
+        common = dict(k_max=swan.k_max, dh=cfg.d_head,
+                      quantized=getattr(swan, "quantize", False))
+        out.append(fold("swan_decode",
+                        sd.precheck(S=max(max_seq, 1), **common)))
         if page_size is not None:
-            # paged-tile grid: sequence blocks are pool pages, so the
-            # block is the page and S spans the per-seq page reservation
-            n_pg = max(max_seq // page_size, 1)
+            # paged-tile grid: sequence blocks are pool pages, so the block
+            # is the page and S spans the per-seq page reservation
             out.append(fold("swan_decode@paged", sd.precheck(
-                B=1, Kv=cfg.n_kv_heads, G=G, dh=cfg.d_head,
-                S=n_pg * page_size, k_max=swan.k_max, b=swan.buffer,
-                block_s=page_size, quantized=quant)))
-        if chunk_q is not None:
-            out.append(fold("swan_chunk_stats", sc.precheck(
-                B=1, Kv=cfg.n_kv_heads, Q=chunk_q, dh=cfg.d_head,
-                S=max(max_seq, 1), k_max=swan.k_max, quantized=quant)))
-            if page_size is not None:
-                n_pg = max(max_seq // page_size, 1)
-                out.append(fold("swan_chunk_stats@paged", sc.precheck(
-                    B=1, Kv=cfg.n_kv_heads, Q=chunk_q, dh=cfg.d_head,
-                    S=n_pg * page_size, k_max=swan.k_max,
-                    block_s=page_size, quantized=quant)))
+                S=max(max_seq // page_size, 1) * page_size,
+                block_s=page_size, paged=True, **common)))
     else:
         out.append(AuditCheck("pallas-precheck/swan_decode", "skip",
                               "no SWAN config on this engine"))
@@ -418,8 +405,7 @@ def run_audit(smoke: bool = True) -> List[AuditCheck]:
 
     page_size = 16
     checks: List[AuditCheck] = kernel_precheck_checks(
-        cfg, swan, max_seq, page_size=page_size,
-        chunk_q=8 * (cfg.n_heads // cfg.n_kv_heads))
+        cfg, swan, max_seq, page_size=page_size)
 
     # xla = pure-JAX reference read path; pallas = kernel-backed decode
     # and chunk attention reads (interpret mode on CPU — the HLO contract

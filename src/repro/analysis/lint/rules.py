@@ -1,7 +1,7 @@
 """swanlint Layer 1 — stdlib-``ast`` rules over the repo's standing
 constraints (ROADMAP §Standing constraints).
 
-Five rules, each with a stable ID so findings can be suppressed inline::
+Four rules, each with a stable ID so findings can be suppressed inline::
 
     # swanlint: disable=SWAN102 -- host fetch point, tokens must cross here
 
@@ -11,11 +11,6 @@ A suppression REQUIRES justification text after the rule list (``--``,
 
 Rules
 -----
-SWAN101  JAX-floor: direct imports/uses of post-0.4.35 APIs
-         (``jax.shard_map``, ``jax.sharding.AxisType``, …) anywhere but
-         the two compat shims ``repro.launch.mesh`` /
-         ``repro.sharding.api``.  The floor is a CI pin; an unguarded
-         use breaks the 0.4.35 leg.
 SWAN102  Host sync on the serve hot path: ``.item()``,
          ``block_until_ready``, ``jax.device_get``, and
          ``float()/int()/bool()/np.asarray()`` applied to values
@@ -51,27 +46,11 @@ from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 RULES: Dict[str, str] = {
     "SWAN100": "malformed swanlint suppression (unknown rule id or "
                "missing justification)",
-    "SWAN101": "post-0.4.35 JAX API used outside the compat shims",
     "SWAN102": "host sync on the serve hot path",
     "SWAN103": "non-power-of-two literal shape in a dispatch builder",
     "SWAN104": "serve-state leaf without a sharding-spec rule",
     "SWAN105": "ad-hoc metrics container outside MetricsRegistry",
 }
-
-# modules allowed to touch post-floor JAX APIs (the version shims)
-FLOOR_SHIM_MODULES = ("repro/launch/mesh.py", "repro/sharding/api.py")
-
-# post-0.4.35 API surface (dotted names); the floor itself
-# (jax.make_mesh) is fine
-POST_FLOOR_APIS = (
-    "jax.shard_map",
-    "jax.sharding.AxisType",
-    "jax.sharding.use_mesh",
-    "jax.sharding.reshard",
-    "jax.sharding.auto_axes",
-    "jax.sharding.explicit_axes",
-    "jax.experimental.shard_map",
-)
 
 # known host fetch points: the functions whose JOB is to move sampled
 # tokens/logits across the device boundary (engine docstrings state the
@@ -265,40 +244,6 @@ def _snippet(lines: Sequence[str], lineno: int) -> str:
 
 
 # ---------------------------------------------------------------------------
-# SWAN101 — JAX floor
-# ---------------------------------------------------------------------------
-
-def _rule_floor(tree: ast.AST, rel: str, lines) -> List[Finding]:
-    if rel.replace("\\", "/").endswith(FLOOR_SHIM_MODULES):
-        return []
-    out: List[Finding] = []
-
-    def hit(lineno, col, api):
-        out.append(Finding(
-            "SWAN101", rel, lineno, col,
-            f"{api} is newer than the JAX 0.4.35 floor — go through "
-            "repro.launch.mesh / repro.sharding.api.shard_map_compat",
-            snippet=_snippet(lines, lineno)))
-
-    for node in ast.walk(tree):
-        if isinstance(node, ast.ImportFrom):
-            mod = node.module or ""
-            for alias in node.names:
-                full = f"{mod}.{alias.name}"
-                if full in POST_FLOOR_APIS or mod in POST_FLOOR_APIS:
-                    hit(node.lineno, node.col_offset, full)
-        elif isinstance(node, ast.Import):
-            for alias in node.names:
-                if alias.name in POST_FLOOR_APIS:
-                    hit(node.lineno, node.col_offset, alias.name)
-        elif isinstance(node, ast.Attribute):
-            d = _dotted(node)
-            if d in POST_FLOOR_APIS:
-                hit(node.lineno, node.col_offset, d)
-    return out
-
-
-# ---------------------------------------------------------------------------
 # SWAN102 — host sync on the serve hot path
 # ---------------------------------------------------------------------------
 
@@ -330,7 +275,7 @@ def _function_index(tree: ast.Module) -> List[_FnInfo]:
 
 def _dispatch_names(tree: ast.Module) -> Set[str]:
     """Attr/local names bound to jitted dispatch callables: RHS is a call
-    to ``jax.jit`` or ``shard_map_compat`` (possibly nested)."""
+    to ``jax.jit`` or ``shard_map_unchecked`` (possibly nested)."""
     out: Set[str] = set()
 
     def jit_call(node) -> bool:
@@ -338,7 +283,7 @@ def _dispatch_names(tree: ast.Module) -> Set[str]:
             if isinstance(n, ast.Call):
                 d = _dotted(n.func)
                 if d in ("jax.jit", "jit") or (
-                        d is not None and d.endswith("shard_map_compat")):
+                        d is not None and d.endswith("shard_map_unchecked")):
                     return True
         return False
 
@@ -641,7 +586,7 @@ def _rule_obs(tree: ast.Module, rel: str, lines) -> List[Finding]:
 # Driver
 # ---------------------------------------------------------------------------
 
-_PER_FILE_RULES = (_rule_floor, _rule_host_sync, _rule_bucketing, _rule_obs)
+_PER_FILE_RULES = (_rule_host_sync, _rule_bucketing, _rule_obs)
 
 
 def lint_source(text: str, rel: str) -> List[Finding]:

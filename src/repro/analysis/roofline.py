@@ -1,8 +1,11 @@
-"""Roofline terms from dry-run records (TPU v5e constants).
+"""Roofline terms from dry-run records and kernel timings.
 
-  compute_s    = flops_per_device / 197 TFLOP/s (bf16)
-  memory_s     = hbm_bytes_per_device / 819 GB/s
-  collective_s = collective_bytes_per_device / 50 GB/s/link
+  compute_s    = flops_per_device / peak bf16 FLOP/s
+  memory_s     = hbm_bytes_per_device / peak HBM bytes/s
+  collective_s = collective_bytes_per_device / ICI bytes/s per link
+
+with the peaks of the device, looked up by ``device_kind`` in ``PEAKS``.
+A device that is not in the table is an error, never a default.
 
 All HLO-derived quantities are per-device (post-SPMD shapes), so the spec's
 "X/(chips × bw)" is applied with per-chip numerators directly.  MODEL_FLOPS
@@ -15,9 +18,24 @@ from __future__ import annotations
 
 from typing import Any, Dict, Optional
 
-PEAK_FLOPS = 197e12      # bf16 per chip
-HBM_BW = 819e9           # bytes/s per chip
-ICI_BW = 50e9            # bytes/s per link
+# Published per-chip peaks keyed by ``jax.Device.device_kind``.  TPU v5e
+# (reported as "TPU v5 lite"): Google Cloud documentation, "TPU v5e" —
+# 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s
+# of inter-chip interconnect (4 links x 50 GB/s).
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bytes_per_s": 819e9, "ici_bytes_per_s": 50e9},
+}
+# the chip the multi-pod dry-run compiles for
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """Peaks of ``device_kind``; raises on a device with no entry."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r} (known: {sorted(PEAKS)})")
+    return PEAKS[device_kind]
 
 _ADVICE = {
     "compute": ("increase arithmetic efficiency: larger per-chip tiles "
@@ -117,18 +135,20 @@ def flash_kernel_flops(*, B: int, H: int, Sq: int, Sk: int, dh: int,
 
 
 def roofline_row(name: str, us_per_call: float, hbm_bytes: int,
-                 flops: float = 0.0, **tags) -> Dict[str, Any]:
+                 flops: float = 0.0, *, device_kind: str,
+                 **tags) -> Dict[str, Any]:
     """One per-kernel roofline table row: the memory-bound (or
-    compute-bound) floor time from the ideal byte/flop model vs the
-    measured call time.  ``achieved_fraction`` is fraction-of-peak on TPU;
-    in interpret mode on CPU it is a tiny consistency number (the gate in
-    benchmarks/bench_kernels.py keys its threshold off the backend)."""
-    mem_s = hbm_bytes / HBM_BW
-    comp_s = flops / PEAK_FLOPS
+    compute-bound) floor time from the ideal byte/flop model vs the call
+    time measured on ``device_kind``.  ``achieved_fraction`` is the
+    roofline share."""
+    pk = peaks(device_kind)
+    mem_s = hbm_bytes / pk["hbm_bytes_per_s"]
+    comp_s = flops / pk["bf16_flops"]
     bound = "compute" if comp_s > mem_s else "memory"
     floor_s = max(mem_s, comp_s)
     meas_s = us_per_call * 1e-6
-    row = {"name": name, "us_per_call": float(us_per_call),
+    row = {"name": name, "device_kind": device_kind,
+           "us_per_call": float(us_per_call),
            "hbm_bytes": int(hbm_bytes), "flops": float(flops),
            "bound": bound, "floor_us": floor_s * 1e6,
            "achieved_bw_gbs": (hbm_bytes / meas_s / 1e9) if meas_s else 0.0,
@@ -141,16 +161,17 @@ def roofline_report(record: Dict[str, Any], cfg, shape,
                     swan=None) -> Dict[str, Any]:
     hlo = record["hlo_cost"]
     n_dev = record["n_devices"]
-    compute_s = hlo["flops"] / PEAK_FLOPS
-    memory_s = hlo["hbm_bytes"] / HBM_BW
-    collective_s = hlo["collective_bytes"] / ICI_BW
+    pk = peaks(DRYRUN_DEVICE_KIND)
+    compute_s = hlo["flops"] / pk["bf16_flops"]
+    memory_s = hlo["hbm_bytes"] / pk["hbm_bytes_per_s"]
+    collective_s = hlo["collective_bytes"] / pk["ici_bytes_per_s"]
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
     bottleneck = max(terms, key=terms.get)
     mf = model_flops(cfg, shape)
     total_hlo_flops = hlo["flops"] * n_dev
     step_s = max(terms.values())
-    ideal_s = mf / (n_dev * PEAK_FLOPS)
+    ideal_s = mf / (n_dev * pk["bf16_flops"])
     out = {
         "compute_s": compute_s,
         "memory_s": memory_s,
@@ -164,5 +185,5 @@ def roofline_report(record: Dict[str, Any], cfg, shape,
     if swan is not None and shape.kind == "decode":
         kb = kernel_model_bytes(cfg, shape, swan)
         out["kernel_model_bytes"] = kb
-        out["kernel_model_memory_s"] = kb / HBM_BW
+        out["kernel_model_memory_s"] = kb / pk["hbm_bytes_per_s"]
     return out

@@ -128,7 +128,7 @@ def _sparse_stats_sharded(qf, cache, swan, sp_len, mesh, seq_axis: str):
     cannot fall back to gathering the compressed cache."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.sharding.api import shard_map_compat
+    from repro.sharding.api import shard_map_unchecked
 
     B = qf.shape[0]
     S = cache["k"]["vals"].shape[2]
@@ -157,7 +157,7 @@ def _sparse_stats_sharded(qf, cache, swan, sp_len, mesh, seq_axis: str):
         o_g = jax.lax.psum(o * corr[..., None], seq_axis)
         return m_g, l_g, o_g
 
-    return shard_map_compat(
+    return shard_map_unchecked(
         local_fn, mesh,
         (P(bspec, None, None, None), side_spec, side_spec, P(bspec)),
         (P(bspec, None, None), P(bspec, None, None),

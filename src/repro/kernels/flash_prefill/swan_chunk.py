@@ -21,6 +21,9 @@ Grid: (B, Kv, S/BS) slab, (B, Kv, Pg) paged — the sequence axis innermost
 so scratch carries.  The paged variant takes each lane's page-table row as
 a scalar-prefetch operand and gathers pool pages directly into VMEM tiles
 (no materialised logical view), exactly like ``swan_decode_paged_pallas``.
+Blocks follow the TPU compiler's layout rules as in ``swan_decode``; the
+per-query ``m``/``l`` columns leave the kernel as the two columns of one
+``[B, Kv, Q, 2]`` output, whose block ends in the array's own dims.
 """
 from __future__ import annotations
 
@@ -33,79 +36,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
-
-VMEM_BYTES_PER_CORE = 16 * 1024 * 1024
-LANE_WIDTH = 128
-SUBLANE_F32 = 8
-
-
-def vmem_footprint(*, bs: int, dh: int, k_max: int, Q: int,
-                   quantized: bool = False) -> int:
-    """Per-grid-step VMEM working set in bytes (double-buffered inputs),
-    mirroring the BlockSpecs in ``swan_chunk_stats_pallas``."""
-    vals_b = 4 if not quantized else 1
-    tile = 2 * (bs * k_max * vals_b + bs * k_max)     # k/v packed vals+idx
-    if quantized:
-        tile += 2 * bs * 4                            # k/v scales
-    tile += Q * dh * 4                                # q block (resident)
-    inputs = 2 * tile                                 # double buffering
-    expand = 2 * bs * dh * 4                          # k_dense + v_dense
-    scratch = 2 * Q * 4 + Q * dh * 4                  # m, l, acc
-    out = 2 * Q * 4 + Q * dh * 4                      # m, l, o
-    return inputs + expand + scratch + out
-
-
-def precheck(*, B: int, Kv: int, Q: int, dh: int, S: int, k_max: int,
-             block_s: int = 256, quantized: bool = False,
-             vmem_budget: int = VMEM_BYTES_PER_CORE) -> dict:
-    """Static grid/VMEM validation for the bulk-chunk stats kernel — same
-    contract as ``repro.kernels.swan_decode.precheck``.  For the paged
-    variant pass ``S = Pg * page_size`` and ``block_s = page_size``."""
-    errors, warnings = [], []
-    bs = min(block_s, S) if S else 0
-    if S <= 0:
-        errors.append(f"empty sparse extent S={S}: caller must short-"
-                      "circuit to zero stats")
-    elif bs <= 0 or S % bs:
-        errors.append(f"sparse length S={S} not divisible by block bs={bs}")
-    if k_max > dh:
-        errors.append(f"k_max={k_max} exceeds dh={dh}: one-hot expansion "
-                      "would scatter out of range")
-    vmem = vmem_footprint(bs=max(bs, 1), dh=dh, k_max=k_max, Q=Q,
-                          quantized=quantized)
-    if vmem > vmem_budget:
-        errors.append(f"VMEM working set {vmem} B exceeds budget "
-                      f"{vmem_budget} B (bs={bs}, k={k_max}, dh={dh}, Q={Q})")
-    if dh % LANE_WIDTH:
-        warnings.append(f"dh={dh} not a multiple of lane width "
-                        f"{LANE_WIDTH}: tiles pad to 128 lanes")
-    if Q % SUBLANE_F32 or (bs and bs % SUBLANE_F32):
-        warnings.append(f"Q={Q}/bs={bs} not multiples of f32 sublane "
-                        f"{SUBLANE_F32}: tiles pad sublanes")
-    return {"errors": errors, "warnings": warnings, "vmem_bytes": vmem}
-
-
-def _expand_packed(vals, idx, bs: int, dh: int, k_max: int):
-    iota = jax.lax.broadcasted_iota(jnp.int32, (bs, dh), 1)
-
-    def body(j, acc):
-        v = jax.lax.dynamic_slice(vals, (0, j), (bs, 1))
-        i = jax.lax.dynamic_slice(idx, (0, j), (bs, 1))
-        return acc + v * (iota == i).astype(jnp.float32)
-
-    return jax.lax.fori_loop(0, k_max, body,
-                             jnp.zeros((bs, dh), jnp.float32))
+from repro.kernels.swan_decode.swan_decode import (
+    NEG_INF, SMEM_WHOLE, expand_packed, scale_spec, softmax_scratch,
+    unit_axis)
 
 
 def _chunk_stats_body(meta_ref, q_ref, kv_ref, ki_ref, vv_ref, vi_ref,
-                      ks_ref, vs_ref, mo_ref, lo_ref, oo_ref,
-                      m_sc, l_sc, acc_sc, *, bs: int, dh: int, k_max: int,
+                      ks_ref, vs_ref, ml_ref, oo_ref,
+                      m_sc, l_sc, acc_sc, *, bs: int, dh: int,
                       n_sblocks: int, quantized: bool):
-    sb = pl.program_id(2)
+    lane, sb = pl.program_id(0), pl.program_id(2)
     Q = q_ref.shape[2]
     scale = 1.0 / math.sqrt(dh)
-    sp_len = meta_ref[0, 0]       # this lane's valid sparse-prefix length
+    sp_len = meta_ref[lane]       # this lane's valid sparse-prefix length
 
     @pl.when(sb == 0)
     def _init():
@@ -114,18 +57,15 @@ def _chunk_stats_body(meta_ref, q_ref, kv_ref, ki_ref, vv_ref, vi_ref,
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
     q = q_ref[0, 0].astype(jnp.float32)                        # [Q, dh]
-    kv = kv_ref[0, 0].astype(jnp.float32)                      # [BS, k]
-    vv = vv_ref[0, 0].astype(jnp.float32)
-    if quantized:
-        kv = kv * ks_ref[0, 0][:, None]
-        vv = vv * vs_ref[0, 0][:, None]
-    ki = ki_ref[0, 0].astype(jnp.int32)
-    vi = vi_ref[0, 0].astype(jnp.int32)
-    k_dense = _expand_packed(kv, ki, bs, dh, k_max)            # [BS, dh]
-    v_dense = _expand_packed(vv, vi, bs, dh, k_max)
+    k_dense = expand_packed(kv_ref[0, 0].astype(jnp.float32),
+                            ki_ref[0, 0], dh)                  # [BS, dh]
+    v_dense = expand_packed(vv_ref[0, 0].astype(jnp.float32),
+                            vi_ref[0, 0], dh)
 
     s = jax.lax.dot_general(q, k_dense, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
+    if quantized:                     # per-token scales of this head
+        s = s * ks_ref[0, 0]                                   # [1, BS]
     t_pos = sb * bs + jax.lax.broadcasted_iota(jnp.int32, (Q, bs), 1)
     s = jnp.where(t_pos < sp_len, s, NEG_INF)
 
@@ -135,6 +75,8 @@ def _chunk_stats_body(meta_ref, q_ref, kv_ref, ki_ref, vv_ref, vi_ref,
     p = jnp.where(t_pos < sp_len, p, 0.0)
     corr = jnp.exp(m_prev - m_new)
     l_sc[...] = l_prev * corr + p.sum(axis=1, keepdims=True)
+    if quantized:
+        p = p * vs_ref[0, 0]
     acc_sc[...] = acc_sc[...] * corr + jax.lax.dot_general(
         p, v_dense, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -146,8 +88,8 @@ def _chunk_stats_body(meta_ref, q_ref, kv_ref, ki_ref, vv_ref, vi_ref,
         # empty-prefix convention of _sparse_stats_bulk: m_safe = 0.0 when
         # no position was valid (all scores stayed at the NEG_INF floor)
         m_safe = jnp.where(m > NEG_INF * 0.5, m, 0.0)
-        mo_ref[0, 0] = m_safe[:, 0]
-        lo_ref[0, 0] = l_sc[...][:, 0]
+        col = jax.lax.broadcasted_iota(jnp.int32, (Q, 2), 1)
+        ml_ref[0, 0] = jnp.where(col == 0, m_safe, l_sc[...])
         oo_ref[0, 0] = acc_sc[...]
 
 
@@ -160,9 +102,9 @@ def _chunk_kernel(*refs, quantized: bool, **static):
         i += 2
     else:
         ks_ref = vs_ref = None
-    mo_ref, lo_ref, oo_ref, m_sc, l_sc, acc_sc = refs[i:i + 6]
+    ml_ref, oo_ref, m_sc, l_sc, acc_sc = refs[i:i + 5]
     _chunk_stats_body(meta_ref, q_ref, kv_ref, ki_ref, vv_ref, vi_ref,
-                      ks_ref, vs_ref, mo_ref, lo_ref, oo_ref,
+                      ks_ref, vs_ref, ml_ref, oo_ref,
                       m_sc, l_sc, acc_sc, quantized=quantized, **static)
 
 
@@ -171,26 +113,43 @@ def _paged_chunk_kernel(tab_ref, *refs, quantized: bool, **static):
     _chunk_kernel(*refs, quantized=quantized, **static)
 
 
-def _stats_out(B: int, Kv: int, Q: int, dh: int, paged: bool):
-    """(out_specs, out_shape) for the three stats outputs."""
-    if paged:
-        m_map = lambda b_, j, s, tab: (b_, j, 0)          # noqa: E731
-        o_map = lambda b_, j, s, tab: (b_, j, 0, 0)       # noqa: E731
+def _chunk_call(kernel, q, tiles, scales, sp_len, tile_map, lane_map, *,
+                bs: int, n_sblocks: int, prefetch=None, interpret=None):
+    """The pallas_call shared by the slab and paged stats kernels.  Returns
+    (m_safe [B,Kv,Q], l [B,Kv,Q], o_unnorm [B,Kv,Q,dh]); m and l leave the
+    kernel as the two columns of one [B,Kv,Q,2] block."""
+    from repro.kernels.dispatch import resolve_interpret
+    B, Kv, Q, dh = q.shape
+    k_max = tiles[0].shape[-1]
+    meta = jnp.broadcast_to(jnp.asarray(sp_len, jnp.int32), (B,))
+    tile = pl.BlockSpec((1, 1, bs, k_max), tile_map)
+    specs = [SMEM_WHOLE, pl.BlockSpec((1, 1, Q, dh), lane_map)] + [tile] * 4
+    if scales:
+        specs += [scale_spec(tile_map, bs)] * 2
+    out_specs = [pl.BlockSpec((1, 1, Q, 2), lane_map),
+                 pl.BlockSpec((1, 1, Q, dh), lane_map)]
+    out_shape = (jax.ShapeDtypeStruct((B, Kv, Q, 2), jnp.float32),
+                 jax.ShapeDtypeStruct((B, Kv, Q, dh), jnp.float32))
+    kernel = functools.partial(kernel, bs=bs, dh=dh, n_sblocks=n_sblocks,
+                               quantized=bool(scales))
+    scratch = softmax_scratch(Q, dh)
+    grid = (B, Kv, n_sblocks)
+    operands = [meta, q, *tiles, *map(unit_axis, scales)]
+    if prefetch is None:
+        call = pl.pallas_call(kernel, grid=grid, in_specs=specs,
+                              out_specs=out_specs, out_shape=out_shape,
+                              scratch_shapes=scratch,
+                              interpret=resolve_interpret(interpret))
     else:
-        m_map = lambda b_, j, s: (b_, j, 0)               # noqa: E731
-        o_map = lambda b_, j, s: (b_, j, 0, 0)            # noqa: E731
-    specs = [pl.BlockSpec((1, 1, Q), m_map),
-             pl.BlockSpec((1, 1, Q), m_map),
-             pl.BlockSpec((1, 1, Q, dh), o_map)]
-    shapes = (jax.ShapeDtypeStruct((B, Kv, Q), jnp.float32),
-              jax.ShapeDtypeStruct((B, Kv, Q), jnp.float32),
-              jax.ShapeDtypeStruct((B, Kv, Q, dh), jnp.float32))
-    return specs, shapes
-
-
-_SCRATCH = lambda Q, dh: [pltpu.VMEM((Q, 1), jnp.float32),    # noqa: E731
-                          pltpu.VMEM((Q, 1), jnp.float32),
-                          pltpu.VMEM((Q, dh), jnp.float32)]
+        call = pl.pallas_call(
+            kernel, out_shape=out_shape,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=grid, in_specs=specs,
+                out_specs=out_specs, scratch_shapes=scratch),
+            interpret=resolve_interpret(interpret))
+        operands = [prefetch] + operands
+    ml, o = call(*operands)
+    return ml[..., 0], ml[..., 1], o
 
 
 def swan_chunk_stats_pallas(q, k_vals, k_idx, v_vals, v_idx, sp_len,
@@ -201,41 +160,14 @@ def swan_chunk_stats_pallas(q, k_vals, k_idx, v_vals, v_idx, sp_len,
     [B,Kv,S,k]; per-lane ``sp_len [B]``.  Returns (m_safe [B,Kv,Q],
     l [B,Kv,Q], o_unnorm [B,Kv,Q,dh]) — drop-in for
     ``swan_attention._sparse_stats_bulk``."""
-    from repro.kernels.dispatch import resolve_interpret
-    B, Kv, Q, dh = q.shape
-    S, k_max = k_vals.shape[2], k_vals.shape[3]
+    S = k_vals.shape[2]
     bs = min(block_s, S)
     assert S > 0 and S % bs == 0, (S, bs)
-    n_sblocks = S // bs
-    quantized = k_scale is not None
-    meta = jnp.broadcast_to(jnp.asarray(sp_len, jnp.int32),
-                            (B,)).reshape(B, 1)
-
-    kernel = functools.partial(_chunk_kernel, bs=bs, dh=dh, k_max=k_max,
-                               n_sblocks=n_sblocks, quantized=quantized)
-    specs = [
-        pl.BlockSpec((1, 1), lambda b_, j, s: (b_, 0)),                # meta
-        pl.BlockSpec((1, 1, Q, dh), lambda b_, j, s: (b_, j, 0, 0)),   # q
-        pl.BlockSpec((1, 1, bs, k_max), lambda b_, j, s: (b_, j, s, 0)),
-        pl.BlockSpec((1, 1, bs, k_max), lambda b_, j, s: (b_, j, s, 0)),
-        pl.BlockSpec((1, 1, bs, k_max), lambda b_, j, s: (b_, j, s, 0)),
-        pl.BlockSpec((1, 1, bs, k_max), lambda b_, j, s: (b_, j, s, 0)),
-    ]
-    operands = [meta, q, k_vals, k_idx, v_vals, v_idx]
-    if quantized:
-        specs += [pl.BlockSpec((1, 1, bs), lambda b_, j, s: (b_, j, s)),
-                  pl.BlockSpec((1, 1, bs), lambda b_, j, s: (b_, j, s))]
-        operands += [k_scale, v_scale]
-    out_specs, out_shape = _stats_out(B, Kv, Q, dh, paged=False)
-    return pl.pallas_call(
-        kernel,
-        grid=(B, Kv, n_sblocks),
-        in_specs=specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=_SCRATCH(Q, dh),
-        interpret=resolve_interpret(interpret),
-    )(*operands)
+    scales = [] if k_scale is None else [k_scale, v_scale]
+    return _chunk_call(_chunk_kernel, q, [k_vals, k_idx, v_vals, v_idx],
+                       scales, sp_len, lambda b_, j, s: (b_, j, s, 0),
+                       lambda b_, j, s: (b_, j, 0, 0), bs=bs,
+                       n_sblocks=S // bs, interpret=interpret)
 
 
 def swan_chunk_stats_paged_pallas(q, pool_k_vals, pool_k_idx, pool_v_vals,
@@ -246,44 +178,14 @@ def swan_chunk_stats_paged_pallas(q, pool_k_vals, pool_k_idx, pool_v_vals,
     ``page_rows [B,Pg]`` gathered into VMEM tiles inside the kernel —
     the chunk path's replacement for ``paged_logical_view`` +
     ``_sparse_stats_bulk``."""
-    from repro.kernels.dispatch import resolve_interpret
-    B, Kv, Q, dh = q.shape
-    _, _, ps, k_max = pool_k_vals.shape
-    Pg = page_rows.shape[1]
+    B, Pg = q.shape[0], page_rows.shape[1]
     assert page_rows.shape == (B, Pg), page_rows.shape
     assert Pg >= 1, "empty page-table prefix: caller must short-circuit"
-    quantized = pool_k_scale is not None
-    meta = jnp.broadcast_to(jnp.asarray(sp_len, jnp.int32),
-                            (B,)).reshape(B, 1)
-
-    kernel = functools.partial(_paged_chunk_kernel, bs=ps, dh=dh,
-                               k_max=k_max, n_sblocks=Pg,
-                               quantized=quantized)
-    tile = lambda b_, j, s, tab: (tab[b_, s], j, 0, 0)     # noqa: E731
-    specs = [
-        pl.BlockSpec((1, 1), lambda b_, j, s, tab: (b_, 0)),           # meta
-        pl.BlockSpec((1, 1, Q, dh), lambda b_, j, s, tab: (b_, j, 0, 0)),
-        pl.BlockSpec((1, 1, ps, k_max), tile),
-        pl.BlockSpec((1, 1, ps, k_max), tile),
-        pl.BlockSpec((1, 1, ps, k_max), tile),
-        pl.BlockSpec((1, 1, ps, k_max), tile),
-    ]
-    operands = [meta, q, pool_k_vals, pool_k_idx, pool_v_vals, pool_v_idx]
-    if quantized:
-        sc = lambda b_, j, s, tab: (tab[b_, s], j, 0)      # noqa: E731
-        specs += [pl.BlockSpec((1, 1, ps), sc), pl.BlockSpec((1, 1, ps), sc)]
-        operands += [pool_k_scale, pool_v_scale]
-    out_specs, out_shape = _stats_out(B, Kv, Q, dh, paged=True)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, Kv, Pg),
-        in_specs=specs,
-        out_specs=out_specs,
-        scratch_shapes=_SCRATCH(Q, dh),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        interpret=resolve_interpret(interpret),
-    )(page_rows, *operands)
+    scales = [] if pool_k_scale is None else [pool_k_scale, pool_v_scale]
+    return _chunk_call(_paged_chunk_kernel, q,
+                       [pool_k_vals, pool_k_idx, pool_v_vals, pool_v_idx],
+                       scales, sp_len,
+                       lambda b_, j, s, tab: (tab[b_, s], j, 0, 0),
+                       lambda b_, j, s, tab: (b_, j, 0, 0),
+                       bs=pool_k_vals.shape[2], n_sblocks=Pg,
+                       prefetch=page_rows, interpret=interpret)

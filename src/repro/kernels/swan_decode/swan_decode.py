@@ -3,22 +3,28 @@
 The kernel consumes the *compressed* cache directly (paper's
 "decompression-free" claim, TPU-native): each grid step DMAs one packed
 sparse tile (vals [BS,k] + idx [BS,k] int8, optionally int8 vals + f32
-scales) from HBM into VMEM, expands it **in registers** via a one-hot
-fori-loop (never materialising a dense cache in HBM), and feeds two MXU
-matmuls (scores, weighted values) through a flash-style online-softmax
-accumulator held in VMEM scratch.  The final grid step folds in the dense
-ring buffer.
+scales) from HBM into VMEM, expands it in VMEM via a one-hot fori-loop
+(never materialising a dense cache in HBM), and feeds two MXU matmuls
+(scores, weighted values) through a flash-style online-softmax accumulator
+held in VMEM scratch.  The final grid step folds in the dense ring buffer.
 
 Grid: (B, Kv, S/BS) — the sequence axis iterates innermost so the scratch
 accumulators carry across sparse tiles.
 
-VMEM budget per step (defaults BS=256, k≤128, dh=128, f32):
-  packed tiles 2·(BS·k·4 + BS·k) ≈ 640 KB, expansion buffer BS·dh·4 =
-  128 KB, buffer tile b·dh·4 ≈ 64 KB, accumulators G·dh·4 — comfortably
-  inside the ~16 MB v5e VMEM with headroom for double buffering.
-dh=128 matches the lane width; BS is sublane-aligned; the j-loop expansion
-is VPU work that overlaps the HBM-bound tile streaming (decode is
-bandwidth-bound, so these FLOPs are free — DESIGN.md §2).
+Layout rules of the TPU compiler (Mosaic), which interpret mode does not
+enforce: the last two dims of every VMEM block are multiples of (8, 128)
+or equal the array's dims.  So the per-lane scalars (decode position,
+sparse length) live whole in SMEM, and ``buf_pos`` and the int8 scales
+get a unit axis (``[B, 1, b]``, ``[.., Kv, 1, S]``), so each grid step
+fetches one ``(1, BS)`` scale row of its own head; the scales are applied
+to the score and probability rows after the matmuls, where they are
+lane-major already.
+On a slab cache that makes a quantized ``block_s`` a multiple of 128 (or
+all of S); a page is always a whole block.  The expansion extracts packed
+column ``j`` with a masked lane reduction, since Mosaic lowers no dynamic
+lane slice.  Whether a shape fits VMEM is decided by the compile
+(``tests/test_tpu_compile.py`` compiles every serve kernel for a v5e at
+OLMo-1B widths); ``precheck`` only mirrors the block rules.
 """
 from __future__ import annotations
 
@@ -33,70 +39,57 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-# ~16 MB/core on v4/v5e; the precheck budgets against this by default
-VMEM_BYTES_PER_CORE = 16 * 1024 * 1024
 LANE_WIDTH = 128        # last-dim tiling unit
-SUBLANE_F32 = 8         # second-to-last-dim tiling unit for f32
+SUBLANE = 8             # second-to-last-dim tiling unit
 
 
-def vmem_footprint(*, bs: int, dh: int, k_max: int, G: int, b: int,
-                   quantized: bool = False) -> int:
-    """Per-grid-step VMEM working set in bytes, double-buffered inputs.
+def precheck(*, S: int, k_max: int, dh: int, block_s: int = 256,
+             quantized: bool = False, paged: bool = False) -> dict:
+    """Static grid and block-layout validation for the SWAN kernels
+    (decode and chunk stats share their packed-tile and scale BlockSpecs).
 
-    Mirrors the BlockSpecs in ``swan_decode_pallas`` plus the in-register
-    expansion buffers and scratch accumulators — the static half of the
-    docstring's budget paragraph, so the swanlint auditor (and tests) can
-    reject a (block_s, k, dh, buffer) configuration before lowering."""
-    vals_b = 4 if not quantized else 1          # f32 vals vs int8+scale
-    tile = 2 * (bs * k_max * vals_b + bs * k_max)     # k/v packed vals+idx
-    tile += 2 * bs * 4                                # k/v scales
-    tile += G * dh * 4                                # q tile
-    tile += 2 * b * dh * 4 + b * 4                    # ring buffer k/v + pos
-    inputs = 2 * tile                                 # double buffering
-    expand = 2 * bs * dh * 4                          # k_dense + v_dense
-    scratch = 2 * G * 4 + G * dh * 4                  # m, l, acc
-    out = G * dh * 4
-    return inputs + expand + scratch + out
-
-
-def precheck(*, B: int, Kv: int, G: int, dh: int, S: int, k_max: int,
-             b: int, block_s: int = 256, quantized: bool = False,
-             vmem_budget: int = VMEM_BYTES_PER_CORE) -> dict:
-    """Static grid/VMEM validation for ``swan_decode_pallas``.
-
-    Returns ``{"errors": [...], "warnings": [...], "vmem_bytes": int}``;
-    errors are conditions under which the kernel asserts or cannot fit,
-    warnings are perf hazards (sub-lane-width dims pad and waste MXU/VPU
-    lanes — fine for smoke configs, wrong for production shapes)."""
-    errors, warnings = [], []
+    Returns ``{"errors": [...], "warnings": [...]}``; errors are shapes the
+    kernel asserts on or the TPU compiler refuses, warnings are perf
+    hazards (a ``dh`` below the lane width pads every tile).  For a paged
+    kernel pass ``S = Pg * page_size``, ``block_s = page_size`` and
+    ``paged=True``: a page is a whole block, so it always conforms.  VMEM
+    is not modelled: the compile is the authority."""
     bs = min(block_s, S)
+    errors = []
     if bs <= 0 or S % bs:
         errors.append(f"sparse length S={S} not divisible by block bs={bs}")
+    elif not paged and bs != S:
+        if bs % SUBLANE:
+            errors.append(f"block bs={bs} is neither S={S} nor a multiple "
+                          f"of the sublane tile {SUBLANE}")
+        if quantized and bs % LANE_WIDTH:
+            errors.append(f"quantized block bs={bs} is neither S={S} nor a "
+                          f"multiple of the lane tile {LANE_WIDTH} (scale "
+                          "blocks are lane-major)")
     if k_max > dh:
         errors.append(f"k_max={k_max} exceeds dh={dh}: one-hot expansion "
                       "would scatter out of range")
-    vmem = vmem_footprint(bs=bs, dh=dh, k_max=k_max, G=G, b=b,
-                          quantized=quantized)
-    if vmem > vmem_budget:
-        errors.append(f"VMEM working set {vmem} B exceeds budget "
-                      f"{vmem_budget} B (bs={bs}, k={k_max}, dh={dh}, b={b})")
+    warnings = []
     if dh % LANE_WIDTH:
         warnings.append(f"dh={dh} not a multiple of lane width "
                         f"{LANE_WIDTH}: tiles pad to 128 lanes")
-    if bs % SUBLANE_F32:
-        warnings.append(f"bs={bs} not a multiple of f32 sublane "
-                        f"{SUBLANE_F32}: tiles pad sublanes")
-    return {"errors": errors, "warnings": warnings, "vmem_bytes": vmem}
+    return {"errors": errors, "warnings": warnings}
 
 
-def _expand_packed(vals, idx, bs: int, dh: int, k_max: int):
-    """One-hot in-register expansion: [BS,k] (+idx) -> dense [BS,dh] f32."""
-    iota = jax.lax.broadcasted_iota(jnp.int32, (bs, dh), 1)
+def expand_packed(vals, idx, dh: int):
+    """One-hot expansion in VMEM: packed [BS,k] vals (f32) + idx -> dense
+    [BS,dh] f32.  Column ``j`` is read by a masked lane sum (exact: one
+    non-zero term), the form Mosaic lowers inside a rolled loop."""
+    bs, k_max = vals.shape
+    lane_k = jax.lax.broadcasted_iota(jnp.int32, (bs, k_max), 1)
+    lane_d = jax.lax.broadcasted_iota(jnp.int32, (bs, dh), 1)
+    idx = idx.astype(jnp.float32)
 
     def body(j, acc):
-        v = jax.lax.dynamic_slice(vals, (0, j), (bs, 1))       # [BS,1]
-        i = jax.lax.dynamic_slice(idx, (0, j), (bs, 1))
-        return acc + v * (iota == i).astype(jnp.float32)
+        pick = lane_k == j
+        v = jnp.sum(jnp.where(pick, vals, 0.0), axis=1, keepdims=True)
+        i = jnp.sum(jnp.where(pick, idx, 0.0), axis=1, keepdims=True)
+        return acc + jnp.where(lane_d == i.astype(jnp.int32), v, 0.0)
 
     return jax.lax.fori_loop(0, k_max, body,
                              jnp.zeros((bs, dh), jnp.float32))
@@ -104,13 +97,13 @@ def _expand_packed(vals, idx, bs: int, dh: int, k_max: int):
 
 def _swan_decode_body(meta_ref, q_ref, kv_ref, ki_ref, vv_ref, vi_ref,
                       ks_ref, vs_ref, bk_ref, bv_ref, bp_ref, o_ref,
-                      m_sc, l_sc, acc_sc, *, bs: int, dh: int, k_max: int,
+                      m_sc, l_sc, acc_sc, *, bs: int, dh: int,
                       n_sblocks: int, quantized: bool):
-    sb = pl.program_id(2)
+    lane, sb = pl.program_id(0), pl.program_id(2)
     G = q_ref.shape[2]
     scale = 1.0 / math.sqrt(dh)
-    pos = meta_ref[0, 0]          # this sequence's decode position
-    sp_len = meta_ref[0, 1]       # this sequence's valid sparse length
+    pos = meta_ref[2 * lane]          # this sequence's decode position
+    sp_len = meta_ref[2 * lane + 1]   # this sequence's valid sparse length
 
     @pl.when(sb == 0)
     def _init():
@@ -121,18 +114,15 @@ def _swan_decode_body(meta_ref, q_ref, kv_ref, ki_ref, vv_ref, vi_ref,
     q = q_ref[0, 0].astype(jnp.float32)                        # [G, dh]
 
     # ---- sparse tile ------------------------------------------------------
-    kv = kv_ref[0, 0].astype(jnp.float32)                      # [BS, k]
-    vv = vv_ref[0, 0].astype(jnp.float32)
-    if quantized:
-        kv = kv * ks_ref[0, 0][:, None]
-        vv = vv * vs_ref[0, 0][:, None]
-    ki = ki_ref[0, 0].astype(jnp.int32)
-    vi = vi_ref[0, 0].astype(jnp.int32)
-    k_dense = _expand_packed(kv, ki, bs, dh, k_max)            # [BS, dh]
-    v_dense = _expand_packed(vv, vi, bs, dh, k_max)
+    k_dense = expand_packed(kv_ref[0, 0].astype(jnp.float32),
+                            ki_ref[0, 0], dh)                  # [BS, dh]
+    v_dense = expand_packed(vv_ref[0, 0].astype(jnp.float32),
+                            vi_ref[0, 0], dh)
 
     s = jax.lax.dot_general(q, k_dense, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
+    if quantized:                     # per-token scales of this head
+        s = s * ks_ref[0, 0]                                   # [1, BS]
     t_pos = sb * bs + jax.lax.broadcasted_iota(jnp.int32, (G, bs), 1)
     s = jnp.where(t_pos < sp_len, s, NEG_INF)
 
@@ -142,6 +132,8 @@ def _swan_decode_body(meta_ref, q_ref, kv_ref, ki_ref, vv_ref, vi_ref,
     p = jnp.where(t_pos < sp_len, p, 0.0)
     corr = jnp.exp(m_prev - m_new)
     l_sc[...] = l_prev * corr + p.sum(axis=1, keepdims=True)
+    if quantized:
+        p = p * vs_ref[0, 0]
     acc_sc[...] = acc_sc[...] * corr + jax.lax.dot_general(
         p, v_dense, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -152,14 +144,14 @@ def _swan_decode_body(meta_ref, q_ref, kv_ref, ki_ref, vv_ref, vi_ref,
     def _finalize():
         bk = bk_ref[0, 0].astype(jnp.float32)                  # [b, dh]
         bv = bv_ref[0, 0].astype(jnp.float32)
-        bpos = bp_ref[0]                                       # [b] (this seq)
+        bpos = bp_ref[0]                                       # [1, b]
         s_b = jax.lax.dot_general(q, bk, (((1,), (1,)), ((), ())),
                                   preferred_element_type=jnp.float32) * scale
         valid = (bpos >= 0) & (bpos <= pos)
-        s_b = jnp.where(valid[None, :], s_b, NEG_INF)
+        s_b = jnp.where(valid, s_b, NEG_INF)
         m_prev, l_prev = m_sc[...], l_sc[...]
         m_fin = jnp.maximum(m_prev, s_b.max(axis=1, keepdims=True))
-        p_b = jnp.where(valid[None, :], jnp.exp(s_b - m_fin), 0.0)
+        p_b = jnp.where(valid, jnp.exp(s_b - m_fin), 0.0)
         corr = jnp.exp(m_prev - m_fin)
         l_fin = l_prev * corr + p_b.sum(axis=1, keepdims=True)
         acc = acc_sc[...] * corr + jax.lax.dot_general(
@@ -187,10 +179,55 @@ def _decode_kernel(*refs, quantized: bool, **static):
 
 
 def _decode_meta(pos, sp_len, B: int):
+    """Per-lane scalars, interleaved ``[pos_0, sp_0, pos_1, sp_1, ...]``
+    (int32 [2B], whole in SMEM)."""
     return jnp.stack([
         jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,)),
         jnp.broadcast_to(jnp.asarray(sp_len, jnp.int32), (B,)),
-    ], axis=1)                                                 # [B, 2]
+    ], axis=1).reshape(2 * B)
+
+
+SMEM_WHOLE = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def softmax_scratch(rows: int, dh: int) -> list:
+    """Online-softmax VMEM scratch for ``rows`` query rows: running max m,
+    running sum l, unnormalised output acc."""
+    return [pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, dh), jnp.float32)]
+
+
+def scale_spec(tile_map, bs: int):
+    """int8 scales as ``[.., Kv, 1, S]`` (see :func:`unit_axis`): the
+    lane-major ``(1, bs)`` row of the packed tile's head and sequence
+    block."""
+    def sc_map(*a):
+        t = tile_map(*a)
+        return (t[0], t[1], 0, t[2])
+    return pl.BlockSpec((1, 1, 1, bs), sc_map)
+
+
+def unit_axis(x):
+    """``[.., n]`` -> ``[.., 1, n]``: a row block then equals the array's
+    second-to-last dim, which the block rules accept."""
+    return x.reshape(x.shape[:-1] + (1, x.shape[-1]))
+
+
+def _specs(tile_map, lane_map, *, G, dh, bs, k_max, b, quantized):
+    """in_specs shared by the slab and paged decode kernels: ``tile_map``
+    addresses a packed tile (and its scale block), ``lane_map`` the
+    per-lane q / ring blocks."""
+    tile = pl.BlockSpec((1, 1, bs, k_max), tile_map)
+    specs = [SMEM_WHOLE,                                       # meta
+             pl.BlockSpec((1, 1, G, dh), lane_map),            # q
+             tile, tile, tile, tile]                           # k/v vals, idx
+    if quantized:
+        specs += [scale_spec(tile_map, bs)] * 2                # k/v scale
+    ring = pl.BlockSpec((1, 1, b, dh), lane_map)
+    pos_map = lambda *a: (lane_map(*a)[0], 0, 0)               # noqa: E731
+    specs += [ring, ring, pl.BlockSpec((1, 1, b), pos_map)]    # buf k/v/pos
+    return specs
 
 
 def swan_decode_pallas(q, k_vals, k_idx, v_vals, v_idx, buf_k, buf_v,
@@ -213,44 +250,25 @@ def swan_decode_pallas(q, k_vals, k_idx, v_vals, v_idx, buf_k, buf_v,
     assert buf_pos.shape == (B, b), buf_pos.shape
     n_sblocks = S // bs
     quantized = k_scale is not None
-    meta = _decode_meta(pos, sp_len, B)
 
     kernel = functools.partial(
-        _decode_kernel, bs=bs, dh=dh, k_max=k_max,
-        n_sblocks=n_sblocks, quantized=quantized)
-    grid = (B, Kv, n_sblocks)
-    specs = [
-        pl.BlockSpec((1, 2), lambda b_, j, s: (b_, 0)),                # meta
-        pl.BlockSpec((1, 1, G, dh), lambda b_, j, s: (b_, j, 0, 0)),   # q
-        pl.BlockSpec((1, 1, bs, k_max), lambda b_, j, s: (b_, j, s, 0)),  # k_vals
-        pl.BlockSpec((1, 1, bs, k_max), lambda b_, j, s: (b_, j, s, 0)),  # k_idx
-        pl.BlockSpec((1, 1, bs, k_max), lambda b_, j, s: (b_, j, s, 0)),  # v_vals
-        pl.BlockSpec((1, 1, bs, k_max), lambda b_, j, s: (b_, j, s, 0)),  # v_idx
-    ]
-    operands = [meta, q, k_vals, k_idx, v_vals, v_idx]
+        _decode_kernel, bs=bs, dh=dh, n_sblocks=n_sblocks,
+        quantized=quantized)
+    specs = _specs(lambda b_, j, s: (b_, j, s, 0),
+                   lambda b_, j, s: (b_, j, 0, 0), G=G, dh=dh, bs=bs,
+                   k_max=k_max, b=b, quantized=quantized)
+    operands = [_decode_meta(pos, sp_len, B), q, k_vals, k_idx, v_vals,
+                v_idx]
     if quantized:
-        specs += [
-            pl.BlockSpec((1, 1, bs), lambda b_, j, s: (b_, j, s)),     # k_scale
-            pl.BlockSpec((1, 1, bs), lambda b_, j, s: (b_, j, s)),     # v_scale
-        ]
-        operands += [k_scale, v_scale]
-    specs += [
-        pl.BlockSpec((1, 1, b, dh), lambda b_, j, s: (b_, j, 0, 0)),   # buf_k
-        pl.BlockSpec((1, 1, b, dh), lambda b_, j, s: (b_, j, 0, 0)),   # buf_v
-        pl.BlockSpec((1, b), lambda b_, j, s: (b_, 0)),                # buf_pos
-    ]
-    operands += [buf_k, buf_v, buf_pos]
+        operands += [unit_axis(k_scale), unit_axis(v_scale)]
+    operands += [buf_k, buf_v, unit_axis(buf_pos)]
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(B, Kv, n_sblocks),
         in_specs=specs,
         out_specs=pl.BlockSpec((1, 1, G, dh), lambda b_, j, s: (b_, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Kv, G, dh), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),   # m
-            pltpu.VMEM((G, 1), jnp.float32),   # l
-            pltpu.VMEM((G, dh), jnp.float32),  # acc
-        ],
+        scratch_shapes=softmax_scratch(G, dh),
         interpret=resolve_interpret(interpret),
     )(*operands)
 
@@ -294,49 +312,27 @@ def swan_decode_paged_pallas(q, pool_k_vals, pool_k_idx, pool_v_vals,
     assert Pg >= 1, "empty page-table prefix: caller must ship >= 1 page"
     assert buf_pos.shape == (B, b), buf_pos.shape
     quantized = pool_k_scale is not None
-    meta = _decode_meta(pos, sp_len, B)
 
     kernel = functools.partial(
-        _paged_decode_kernel, bs=ps, dh=dh, k_max=k_max,
-        n_sblocks=Pg, quantized=quantized)
-    # NOTE index-map signatures: (grid indices..., scalar-prefetch refs...)
-    specs = [
-        pl.BlockSpec((1, 2), lambda b_, j, s, tab: (b_, 0)),            # meta
-        pl.BlockSpec((1, 1, G, dh), lambda b_, j, s, tab: (b_, j, 0, 0)),  # q
-        # pool tiles: the paged VMEM gather — physical page from the table
-        pl.BlockSpec((1, 1, ps, k_max),
-                     lambda b_, j, s, tab: (tab[b_, s], j, 0, 0)),      # k_vals
-        pl.BlockSpec((1, 1, ps, k_max),
-                     lambda b_, j, s, tab: (tab[b_, s], j, 0, 0)),      # k_idx
-        pl.BlockSpec((1, 1, ps, k_max),
-                     lambda b_, j, s, tab: (tab[b_, s], j, 0, 0)),      # v_vals
-        pl.BlockSpec((1, 1, ps, k_max),
-                     lambda b_, j, s, tab: (tab[b_, s], j, 0, 0)),      # v_idx
-    ]
-    operands = [meta, q, pool_k_vals, pool_k_idx, pool_v_vals, pool_v_idx]
+        _paged_decode_kernel, bs=ps, dh=dh, n_sblocks=Pg,
+        quantized=quantized)
+    # index-map signatures: (grid indices..., scalar-prefetch refs...);
+    # pool tiles are the paged VMEM gather — physical page from the table
+    specs = _specs(lambda b_, j, s, tab: (tab[b_, s], j, 0, 0),
+                   lambda b_, j, s, tab: (b_, j, 0, 0), G=G, dh=dh, bs=ps,
+                   k_max=k_max, b=b, quantized=quantized)
+    operands = [_decode_meta(pos, sp_len, B), q, pool_k_vals, pool_k_idx,
+                pool_v_vals, pool_v_idx]
     if quantized:
-        specs += [
-            pl.BlockSpec((1, 1, ps), lambda b_, j, s, tab: (tab[b_, s], j, 0)),
-            pl.BlockSpec((1, 1, ps), lambda b_, j, s, tab: (tab[b_, s], j, 0)),
-        ]
-        operands += [pool_k_scale, pool_v_scale]
-    specs += [
-        pl.BlockSpec((1, 1, b, dh), lambda b_, j, s, tab: (b_, j, 0, 0)),  # buf_k
-        pl.BlockSpec((1, 1, b, dh), lambda b_, j, s, tab: (b_, j, 0, 0)),  # buf_v
-        pl.BlockSpec((1, b), lambda b_, j, s, tab: (b_, 0)),            # buf_pos
-    ]
-    operands += [buf_k, buf_v, buf_pos]
+        operands += [unit_axis(pool_k_scale), unit_axis(pool_v_scale)]
+    operands += [buf_k, buf_v, unit_axis(buf_pos)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, Kv, Pg),
         in_specs=specs,
         out_specs=pl.BlockSpec((1, 1, G, dh),
                                lambda b_, j, s, tab: (b_, j, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),   # m
-            pltpu.VMEM((G, 1), jnp.float32),   # l
-            pltpu.VMEM((G, dh), jnp.float32),  # acc
-        ],
+        scratch_shapes=softmax_scratch(G, dh),
     )
     return pl.pallas_call(
         kernel,

@@ -8,20 +8,10 @@ count (1 CPU here).
 from __future__ import annotations
 
 import jax
-
-try:   # newer jax; older releases have neither AxisType nor axis_types
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _mk(shape, axes):
-    if not hasattr(jax, "make_mesh"):   # pre-0.4.35: build the Mesh directly
-        from jax.experimental import mesh_utils
-        from jax.sharding import Mesh
-        return Mesh(mesh_utils.create_device_mesh(shape), axes)
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))
 
 

@@ -30,10 +30,14 @@ from repro.runtime.serve_engine import Request, ServeEngine
 from repro.runtime.serve_loop import ServeSession, calibrate_swan
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
+    """The launcher's command line (``argv=None`` reads ``sys.argv``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="PRNG seed of the fresh weights (ignored for the "
+                         "restored parts of --ckpt-dir)")
     ap.add_argument("--ckpt-dir", help="restore params from a checkpoint")
     ap.add_argument("--swan", action="store_true")
     ap.add_argument("--k", type=int, default=None)
@@ -105,10 +109,12 @@ def main():
                     help="engine: overlap host scheduling with the decode "
                          "token transfer (copy_to_host_async at dispatch, "
                          "resolved at the next step; token-identical)")
-    ap.add_argument("--compilation-cache-dir", default=None,
-                    help="persist JAX's compilation cache here so engine "
+    ap.add_argument("--compilation-cache", action="store_true",
+                    help="persist JAX's compilation cache so engine "
                          "restarts reload compiled executables from disk "
-                         "instead of recompiling the family")
+                         "instead of recompiling the family: in "
+                         "$JAX_COMPILATION_CACHE_DIR where set, else in "
+                         ".jax_cache/ at the root of the checkout")
     ap.add_argument("--admission", default="fifo", choices=["fifo", "srf"],
                     help="engine: admission policy — fifo, or srf "
                          "(shortest-remaining-first: bounds TTFT when the "
@@ -126,7 +132,7 @@ def main():
                          "this many engine steps into --profile-dir")
     ap.add_argument("--profile-dir", default="profile",
                     help="engine: jax.profiler trace output directory")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.prefill_chunk and not args.engine:
         raise SystemExit("--prefill-chunk requires --engine")
     if ((args.prefill_slots > 1 or args.prefill_budget is not None)
@@ -146,15 +152,16 @@ def main():
                          "(the kernels back the SWAN serve read path)")
     if (args.warmup or args.async_fetch) and not args.engine:
         raise SystemExit("--warmup/--async-fetch require --engine")
-    if args.compilation_cache_dir:
-        # before any compile happens, so the whole family lands on disk
-        from repro.runtime.warmup import enable_compilation_cache
-        enable_compilation_cache(args.compilation_cache_dir)
-        print(f"compilation cache -> {args.compilation_cache_dir}")
+    return args
 
+
+def load_model(args):
+    """Weights (fresh from ``--seed``, or restored), then SWAN calibration
+    and projection absorption.  Returns (cfg, params, swan, projections);
+    swan/projections are None without ``--swan``."""
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     api = get_model(cfg)
-    params = api.init_params(jax.random.PRNGKey(0), cfg)
+    params = api.init_params(jax.random.PRNGKey(args.seed), cfg)
     if args.ckpt_dir:
         ck = Checkpointer(args.ckpt_dir)
         step = ck.latest_step()
@@ -176,7 +183,16 @@ def main():
         params = api.absorb(params, cfg, projections)
         print(f"SWAN: k_max={swan.k_max}/{cfg.d_head} buffer={b} "
               f"mode={swan.mode} int8={swan.quantize}")
+    return cfg, params, swan, projections
 
+
+def main(argv=None):
+    args = parse_args(argv)
+    if args.compilation_cache:
+        # before any compile happens, so the whole family lands on disk
+        from repro.runtime.warmup import enable_compilation_cache
+        print(f"compilation cache -> {enable_compilation_cache()}")
+    cfg, params, swan, projections = load_model(args)
     if args.engine:
         _run_engine(cfg, params, swan, projections, args)
         return
@@ -219,20 +235,29 @@ def _serve_mesh(args):
     return None
 
 
+def build_engine(cfg, params, swan, projections, args, mesh=None,
+                 trace=None, use_pallas=None) -> ServeEngine:
+    """The engine the launcher serves with.  ``use_pallas`` overrides
+    ``--use-pallas`` (None keeps the flag's value)."""
+    return ServeEngine(cfg, params, swan=swan, projections=projections,
+                       max_seq=args.max_seq, n_slots=args.batch,
+                       paged=args.paged, page_size=args.page_size,
+                       n_pages=args.pool_pages,
+                       prefill_chunk=args.prefill_chunk,
+                       prefill_slots=args.prefill_slots,
+                       prefill_budget=args.prefill_budget,
+                       mesh=mesh, pool_grow=args.pool_grow,
+                       admission=args.admission, trace=trace,
+                       use_pallas=(args.use_pallas if use_pallas is None
+                                   else use_pallas),
+                       async_fetch=args.async_fetch)
+
+
 def _run_engine(cfg, params, swan, projections, args):
     mesh = _serve_mesh(args)
     trace = EventTrace(args.trace_out, keep=False) if args.trace_out else None
-    eng = ServeEngine(cfg, params, swan=swan, projections=projections,
-                      max_seq=args.max_seq, n_slots=args.batch,
-                      paged=args.paged, page_size=args.page_size,
-                      n_pages=args.pool_pages,
-                      prefill_chunk=args.prefill_chunk,
-                      prefill_slots=args.prefill_slots,
-                      prefill_budget=args.prefill_budget,
-                      mesh=mesh, pool_grow=args.pool_grow,
-                      admission=args.admission, trace=trace,
-                      use_pallas=args.use_pallas,
-                      async_fetch=args.async_fetch)
+    eng = build_engine(cfg, params, swan, projections, args, mesh=mesh,
+                       trace=trace)
     if args.warmup:
         rep = eng.warmup(max_prompt_len=args.max_prompt_len)
         print(f"warmup: {rep['census']['total']} executables, "

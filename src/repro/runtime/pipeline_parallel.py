@@ -84,9 +84,9 @@ def pipeline_apply(layer_fn: Callable, stage_params: Params, x: jnp.ndarray,
         outputs = jnp.where(stage == n_stages - 1, outputs, 0.0)
         return jax.lax.psum(outputs, axis)
 
-    from repro.sharding.api import shard_map_compat
+    from repro.sharding.api import shard_map_unchecked
 
     in_specs = (P(axis), P())       # stage params sharded; input replicated
-    out = shard_map_compat(local_fn, mesh, in_specs,
-                           P())(stage_params, micro)
+    out = shard_map_unchecked(local_fn, mesh, in_specs,
+                              P())(stage_params, micro)
     return out.reshape(B, *x.shape[1:])

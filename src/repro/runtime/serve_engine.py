@@ -35,8 +35,7 @@ the pool — ``repro.runtime.page_pool`` keeps one free list per shard with
 shard-local physical page indices and a per-shard trash page), the
 budgeted round-robin prefill selection runs independently per shard, and
 retirement returns pages to the owning shard's free list.  Every jitted
-dispatch goes through ``sharding.api.shard_map_compat`` (jax.shard_map on
-new releases, jax.experimental.shard_map at the JAX 0.4.35 floor) with
+dispatch goes through ``sharding.api.shard_map_unchecked`` with
 those specs, so each shard executes exactly the single-device engine's
 computation on its local block — no cross-shard collectives anywhere on
 the serve path — while the engine still issues exactly ONE prefill-chunk
@@ -186,7 +185,7 @@ from repro.obs.trace import EventTrace, StepProfiler
 from repro.runtime.page_pool import PagePool, PagePoolExhausted
 from repro.runtime.sampling import sample_token
 from repro.runtime.serve_loop import serve_cache_report
-from repro.sharding.api import shard_map_compat
+from repro.sharding.api import shard_map_unchecked
 from repro.sharding.serve_specs import sanitize_tree, serve_state_pspecs
 from repro.sharding.specs import dp_axes, params_pspecs
 
@@ -569,14 +568,15 @@ class ServeEngine:
                                   tab), (lane2, lane, st))
             # monolithic admission: the batch=1 prefill is replicated
             # compute (every shard runs it; only the owner's insert lands)
-            prefill_fn = shard_map_compat(prefill_fn, mesh,
-                                          (rep, rep, rep, rep, rep),
-                                          (rep, rep))
-            decode_fn = shard_map_compat(decode_fn, mesh,
-                                         *self._decode_specs)
-            insert_fn = shard_map_compat(insert_fn, mesh, (st, rep, rep), st)
-            insert_paged_fn = shard_map_compat(insert_paged_fn, mesh,
-                                               (st, rep, rep, rep), st)
+            prefill_fn = shard_map_unchecked(prefill_fn, mesh,
+                                             (rep, rep, rep, rep, rep),
+                                             (rep, rep))
+            decode_fn = shard_map_unchecked(decode_fn, mesh,
+                                            *self._decode_specs)
+            insert_fn = shard_map_unchecked(insert_fn, mesh,
+                                            (st, rep, rep), st)
+            insert_paged_fn = shard_map_unchecked(insert_paged_fn, mesh,
+                                                  (st, rep, rep, rep), st)
         if jit:
             self._prefill = jax.jit(prefill_fn, donate_argnums=(2,))
             self._decode = jax.jit(decode_fn, donate_argnums=(5,))
@@ -1158,7 +1158,7 @@ class ServeEngine:
             fn = pad_pool
             if self.mesh is not None:
                 specs = self._state_specs["pool"]
-                fn = shard_map_compat(fn, self.mesh, (specs,), specs)
+                fn = shard_map_unchecked(fn, self.mesh, (specs,), specs)
             if self._jit:
                 fn = jax.jit(fn, donate_argnums=(0,))
             self._grow_fns[extra] = fn
@@ -1341,7 +1341,7 @@ class ServeEngine:
         if fn is None:
             fn = self._make_chunk_fn(prefix)
             if self.mesh is not None:
-                fn = shard_map_compat(fn, self.mesh, *self._chunk_specs)
+                fn = shard_map_unchecked(fn, self.mesh, *self._chunk_specs)
             if self._jit:
                 fn = jax.jit(fn, donate_argnums=(2,))
             self._chunk_fns[prefix] = fn

@@ -45,8 +45,10 @@ control-plane event, and ``_grow_pool`` re-warms the whole family anyway
 """
 from __future__ import annotations
 
+import os
 import time
 from dataclasses import dataclass
+from pathlib import Path
 from types import SimpleNamespace
 from typing import Any, Dict, List, Optional
 
@@ -116,7 +118,8 @@ def executable_family(eng, max_prompt_len: Optional[int] = None
     ``census >= expected`` bucket by bucket).  ``items`` are ordered so a
     fetch item always follows the dispatch item that produces its source
     logits.  ``max_prompt_len`` (admission-side bound on prompt tokens)
-    trims the slab read-prefix and monolithic pad families."""
+    trims the slab read-prefix, paged chunk page-table and monolithic pad
+    families."""
     items: List[WarmupItem] = []
     skipped: List[str] = []
     prompt_cap = min(max_prompt_len or eng.max_seq, eng.max_seq)
@@ -140,11 +143,17 @@ def executable_family(eng, max_prompt_len: Optional[int] = None
     if eng.prefill_chunk is not None:
         pl_buckets = _pow2_buckets(eng._pow2(eng.prefill_slots))
         c_buckets = _pow2_buckets(eng.prefill_chunk)
+        if eng.paged:
+            # a chunk ships the pow2 bucket of its lanes' mapped pages,
+            # which cover at most the prompt's own tokens
+            ps, pps = eng.pool.page_size, eng.pool.pages_per_seq
+            chunk_widths = _pow2_buckets(
+                min(eng._pow2(-(-prompt_cap // ps)), pps))
         for pl in pl_buckets:
             lanes = eng.dp * pl
             for c in c_buckets:
                 if eng.paged:
-                    for w in _pow2_buckets(eng.pool.pages_per_seq):
+                    for w in chunk_widths:
                         items.append(WarmupItem(
                             "chunk", n_lanes=lanes, chunk=c, page_bucket=w))
                         exp_chunk["paged"] = exp_chunk.get("paged", 0) + 1
@@ -343,17 +352,21 @@ def warmup_engine(eng, max_prompt_len: Optional[int] = None
     return report
 
 
-def enable_compilation_cache(path: str) -> None:
-    """Point JAX's persistent compilation cache at ``path`` so engine
-    restarts reload compiled executables from disk instead of recompiling
-    the family (`--compilation-cache-dir` on ``launch/serve.py``).  The
-    threshold knobs are best-effort (older releases lack them): serve
-    executables are small and the whole point is caching everything."""
+# fixed, inside the checkout: a path built from a temp name, a pid or the
+# time would be a new, empty directory in every process and never hit
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache and cache every
+    executable (serve executables are small; the point is caching the
+    whole family), so engine restarts reload the warmed family from disk.
+    The directory is ``JAX_COMPILATION_CACHE_DIR`` where it is set, else
+    ``.jax_cache/`` at the root of this checkout.  Returns the directory."""
     import jax
-    jax.config.update("jax_compilation_cache_dir", str(path))
-    for opt, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                     ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(opt, val)
-        except Exception:        # knob absent on this release — fine
-            pass
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(
+        DEFAULT_CACHE_DIR)
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return path

@@ -54,13 +54,9 @@ def shard(x, kind: str):
     return jax.lax.with_sharding_constraint(x, s)
 
 
-def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """Version-portable shard_map with replication checking off:
-    jax.shard_map (>= 0.4.35, ``check_vma``) falling back to
-    jax.experimental.shard_map (``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+def shard_map_unchecked(fn, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with replication checking off: the serve and
+    split-S bodies are lane-local, so their outputs' replication is the
+    caller's contract, not something the tracer can prove."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

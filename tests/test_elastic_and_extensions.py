@@ -97,7 +97,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.launch.mesh import make_mesh
 from repro.runtime.grad_compress import dp_int8_allreduce
-from repro.sharding.api import shard_map_compat
+from repro.sharding.api import shard_map_unchecked
 
 mesh = make_mesh((4,), ("data",))
 g = jax.random.normal(jax.random.PRNGKey(0), (4, 256))  # per-shard rows
@@ -105,7 +105,7 @@ g = jax.random.normal(jax.random.PRNGKey(0), (4, 256))  # per-shard rows
 def f(g):
     return dp_int8_allreduce({"w": g}, "data")["w"]
 
-out = jax.jit(shard_map_compat(f, mesh, (P("data"),), P("data")))(g)
+out = jax.jit(shard_map_unchecked(f, mesh, (P("data"),), P("data")))(g)
 # every shard's output row == mean of all rows (up to int8 error)
 mean = g.mean(axis=0)
 err = float(jnp.max(jnp.abs(out - mean[None])))

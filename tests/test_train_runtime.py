@@ -95,7 +95,7 @@ def test_dp_int8_allreduce_single_device():
     """On a 1-device mesh the compressed all-reduce reduces to the identity
     quant/dequant round."""
     from repro.launch.mesh import make_mesh
-    from repro.sharding.api import shard_map_compat
+    from repro.sharding.api import shard_map_unchecked
     from jax.sharding import PartitionSpec as P
     mesh = make_mesh((1,), ("data",))
     g = {"w": jax.random.normal(jax.random.PRNGKey(2), (64, 8))}
@@ -103,6 +103,6 @@ def test_dp_int8_allreduce_single_device():
     def f(g):
         return dp_int8_allreduce(g, "data")
 
-    out = jax.jit(shard_map_compat(f, mesh, (P(),), P()))(g)
+    out = jax.jit(shard_map_unchecked(f, mesh, (P(),), P()))(g)
     err = jnp.max(jnp.abs(out["w"] - g["w"]))
     assert float(err) <= float(jnp.max(jnp.abs(g["w"]))) / 127.0 + 1e-6

@@ -291,3 +291,58 @@ def test_pool_growth_rewarms_family(setup):
     eng.run([Request(uid="again", tokens=list(reqs[0].tokens),
                      max_new_tokens=8, k=4)])
     assert compile_events.total() - c0 == 0
+
+
+# ---------------------------------------------------------------------------
+# Persistent compile cache location
+# ---------------------------------------------------------------------------
+
+def _record_config(monkeypatch):
+    seen = {}
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: seen.__setitem__(name, value))
+    return seen
+
+
+def test_compilation_cache_uses_env_dir(monkeypatch, tmp_path):
+    from repro.runtime.warmup import enable_compilation_cache
+    seen = _record_config(monkeypatch)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert enable_compilation_cache() == str(tmp_path)
+    assert seen["jax_compilation_cache_dir"] == str(tmp_path)
+    assert seen["jax_persistent_cache_min_compile_time_secs"] == 0.0
+
+
+def test_compilation_cache_defaults_to_fixed_checkout_dir(monkeypatch):
+    import os
+    from repro.runtime.warmup import DEFAULT_CACHE_DIR, \
+        enable_compilation_cache
+    seen = _record_config(monkeypatch)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    path = enable_compilation_cache()
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert path == str(DEFAULT_CACHE_DIR) == os.path.join(repo, ".jax_cache")
+    assert seen["jax_compilation_cache_dir"] == path
+    assert enable_compilation_cache() == path          # same path every call
+    with open(os.path.join(repo, ".gitignore")) as fh:
+        assert ".jax_cache/" in fh.read().split()
+
+
+def test_persistent_cache_events_are_counted():
+    import jax.monitoring
+    compile_events.install()
+    c0 = compile_events.persistent_cache()
+    jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+    jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+    jax.monitoring.record_event("/jax/compilation_cache/cache_misses")
+    jax.monitoring.record_event("/jax/compilation_cache/tasks_using_cache")
+    c1 = compile_events.persistent_cache()
+    assert (c1["hits"] - c0["hits"], c1["writes"] - c0["writes"]) == (2, 1)
+
+
+def test_compile_seconds_follow_compile_events():
+    compile_events.install()
+    n0, s0 = compile_events.total(), compile_events.seconds()
+    jax.jit(lambda x: x * 3 + 1)(np.arange(7.0)).block_until_ready()
+    assert compile_events.total() - n0 >= 1
+    assert compile_events.seconds() - s0 > 0.0
