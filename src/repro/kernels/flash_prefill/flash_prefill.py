@@ -131,6 +131,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
                                n_kblocks=nk, causal=causal)
     out = pl.pallas_call(
         kernel,
+        name="flash_prefill",
         grid=(B, H, nq, nk),
         in_specs=[
             pl.BlockSpec((1, 1, bq, dh), lambda b, h, i, j: (b, h, i, 0)),

@@ -37,8 +37,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.swan_decode.swan_decode import (
-    NEG_INF, SMEM_WHOLE, expand_packed, scale_spec, softmax_scratch,
-    unit_axis)
+    NEG_INF, SMEM_WHOLE, expand_packed, kernel_name, scale_spec,
+    softmax_scratch, unit_axis)
 
 
 def _chunk_stats_body(meta_ref, q_ref, kv_ref, ki_ref, vv_ref, vi_ref,
@@ -114,7 +114,8 @@ def _paged_chunk_kernel(tab_ref, *refs, quantized: bool, **static):
 
 
 def _chunk_call(kernel, q, tiles, scales, sp_len, tile_map, lane_map, *,
-                bs: int, n_sblocks: int, prefetch=None, interpret=None):
+                bs: int, n_sblocks: int, name: str, prefetch=None,
+                interpret=None):
     """The pallas_call shared by the slab and paged stats kernels.  Returns
     (m_safe [B,Kv,Q], l [B,Kv,Q], o_unnorm [B,Kv,Q,dh]); m and l leave the
     kernel as the two columns of one [B,Kv,Q,2] block."""
@@ -135,14 +136,15 @@ def _chunk_call(kernel, q, tiles, scales, sp_len, tile_map, lane_map, *,
     scratch = softmax_scratch(Q, dh)
     grid = (B, Kv, n_sblocks)
     operands = [meta, q, *tiles, *map(unit_axis, scales)]
+    name = kernel_name(name, bool(scales))
     if prefetch is None:
         call = pl.pallas_call(kernel, grid=grid, in_specs=specs,
                               out_specs=out_specs, out_shape=out_shape,
-                              scratch_shapes=scratch,
+                              scratch_shapes=scratch, name=name,
                               interpret=resolve_interpret(interpret))
     else:
         call = pl.pallas_call(
-            kernel, out_shape=out_shape,
+            kernel, out_shape=out_shape, name=name,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1, grid=grid, in_specs=specs,
                 out_specs=out_specs, scratch_shapes=scratch),
@@ -167,7 +169,8 @@ def swan_chunk_stats_pallas(q, k_vals, k_idx, v_vals, v_idx, sp_len,
     return _chunk_call(_chunk_kernel, q, [k_vals, k_idx, v_vals, v_idx],
                        scales, sp_len, lambda b_, j, s: (b_, j, s, 0),
                        lambda b_, j, s: (b_, j, 0, 0), bs=bs,
-                       n_sblocks=S // bs, interpret=interpret)
+                       n_sblocks=S // bs, name="swan_chunk_stats_slab",
+                       interpret=interpret)
 
 
 def swan_chunk_stats_paged_pallas(q, pool_k_vals, pool_k_idx, pool_v_vals,
@@ -188,4 +191,5 @@ def swan_chunk_stats_paged_pallas(q, pool_k_vals, pool_k_idx, pool_v_vals,
                        lambda b_, j, s, tab: (tab[b_, s], j, 0, 0),
                        lambda b_, j, s, tab: (b_, j, 0, 0),
                        bs=pool_k_vals.shape[2], n_sblocks=Pg,
-                       prefetch=page_rows, interpret=interpret)
+                       name="swan_chunk_stats_paged", prefetch=page_rows,
+                       interpret=interpret)

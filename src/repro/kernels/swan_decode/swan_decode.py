@@ -190,6 +190,19 @@ def _decode_meta(pos, sp_len, B: int):
 SMEM_WHOLE = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
+def kernel_name(base: str, quantized: bool) -> str:
+    """The stable ``pallas_call`` name a profile shows for a kernel
+    variant: ``base``, plus ``_int8`` over an int8-quantized cache."""
+    return base + "_int8" if quantized else base
+
+
+def paged_decode_grid(n_slots: int, n_kv: int, n_pages: int) -> tuple:
+    """The paged decode kernel's grid: one step per (slot, KV head, page
+    of the shipped table prefix), idle slots and unmapped pages included.
+    The serve engine counts the kernel's (slot, page) cells from it."""
+    return (n_slots, n_kv, n_pages)
+
+
 def softmax_scratch(rows: int, dh: int) -> list:
     """Online-softmax VMEM scratch for ``rows`` query rows: running max m,
     running sum l, unnormalised output acc."""
@@ -264,6 +277,7 @@ def swan_decode_pallas(q, k_vals, k_idx, v_vals, v_idx, buf_k, buf_v,
     operands += [buf_k, buf_v, unit_axis(buf_pos)]
     return pl.pallas_call(
         kernel,
+        name=kernel_name("swan_decode_slab", quantized),
         grid=(B, Kv, n_sblocks),
         in_specs=specs,
         out_specs=pl.BlockSpec((1, 1, G, dh), lambda b_, j, s: (b_, j, 0, 0)),
@@ -328,7 +342,7 @@ def swan_decode_paged_pallas(q, pool_k_vals, pool_k_idx, pool_v_vals,
     operands += [buf_k, buf_v, unit_axis(buf_pos)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B, Kv, Pg),
+        grid=paged_decode_grid(B, Kv, Pg),
         in_specs=specs,
         out_specs=pl.BlockSpec((1, 1, G, dh),
                                lambda b_, j, s, tab: (b_, j, 0, 0)),
@@ -336,6 +350,7 @@ def swan_decode_paged_pallas(q, pool_k_vals, pool_k_idx, pool_v_vals,
     )
     return pl.pallas_call(
         kernel,
+        name=kernel_name("swan_decode_paged", quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Kv, G, dh), q.dtype),
         interpret=resolve_interpret(interpret),

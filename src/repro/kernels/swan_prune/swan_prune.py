@@ -65,6 +65,7 @@ def swan_prune_pallas(x, p_rot, k_max: int, *, tile: int = 256,
     kernel = functools.partial(_prune_kernel, t=t, dh=dh, k_max=k_max)
     return pl.pallas_call(
         kernel,
+        name="swan_prune",
         grid=(B, Kv, S // t),
         in_specs=[
             pl.BlockSpec((1, 1, t, dh), lambda b, j, s: (b, j, s, 0)),

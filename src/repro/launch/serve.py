@@ -129,7 +129,9 @@ def parse_args(argv=None) -> argparse.Namespace:
                          "map/free, ...) to this path (repro.obs.trace)")
     ap.add_argument("--profile-steps", type=int, default=None,
                     help="engine: capture one jax.profiler trace spanning "
-                         "this many engine steps into --profile-dir")
+                         "this many engine steps into --profile-dir (Python "
+                         "tracer off, host tracer level 1: the engine.* "
+                         "spans)")
     ap.add_argument("--profile-dir", default="profile",
                     help="engine: jax.profiler trace output directory")
     args = ap.parse_args(argv)

@@ -277,8 +277,9 @@ def _swan_layer_decode(lp: Params, p_qk_l: jnp.ndarray, cache_l: Params,
     mesh, seq_axis = _swan_seq_ctx()
     kern = use_pallas and mesh is None and pallas_decode_supported(swan)
     if page_tab is None:
-        cache_l = hc.swan_cache_insert_decode(cache_l, swan, cfg, k_hat, v,
-                                              pos, k_act=k_act)
+        with jax.named_scope("winnow"):
+            cache_l = hc.swan_cache_insert_decode(cache_l, swan, cfg, k_hat,
+                                                  v, pos, k_act=k_act)
         if kern and cache_l["k"]["vals"].shape[2] > 0:
             from repro.kernels.swan_decode import ops as sdk
             o = sdk.swan_decode_from_cache(q_hat, cache_l, swan, pos,
@@ -287,8 +288,9 @@ def _swan_layer_decode(lp: Params, p_qk_l: jnp.ndarray, cache_l: Params,
             o = swa.swan_decode_attention(q_hat, cache_l, swan, cfg, pos,
                                           mesh=mesh, seq_axis=seq_axis)
     else:
-        cache_l = pc.paged_insert_decode(cache_l, swan, cfg, k_hat, v, pos,
-                                         page_tab, k_act=k_act)
+        with jax.named_scope("winnow"):
+            cache_l = pc.paged_insert_decode(cache_l, swan, cfg, k_hat, v,
+                                             pos, page_tab, k_act=k_act)
         if kern and page_tab.shape[1] > 0:
             from repro.kernels.swan_decode import ops as sdk
             o = sdk.swan_decode_paged_from_cache(q_hat, cache_l, swan, pos,
@@ -455,11 +457,14 @@ def _swan_layer_prefill_chunk(lp: Params, p_qk_l, cache_l: Params, cfg, swan,
         o = swa.swan_chunk_prefill_attention(q_hat, k_hat, v, view, swan,
                                              cfg, start, true_len,
                                              sparse_stats=stats)
-        dest, packed_k, packed_v, upd = hc.chunk_evict_winnow(
-            ring, swan, k_hat, v, start, true_len, k_act=k_act)
-        ring_new = {**ring, **upd}
-        out_l["k"] = hc.write_sparse_rows(cache_l["k"], packed_k, slot, dest)
-        out_l["v"] = hc.write_sparse_rows(cache_l["v"], packed_v, slot, dest)
+        with jax.named_scope("winnow"):
+            dest, packed_k, packed_v, upd = hc.chunk_evict_winnow(
+                ring, swan, k_hat, v, start, true_len, k_act=k_act)
+            ring_new = {**ring, **upd}
+            out_l["k"] = hc.write_sparse_rows(cache_l["k"], packed_k, slot,
+                                              dest)
+            out_l["v"] = hc.write_sparse_rows(cache_l["v"], packed_v, slot,
+                                              dest)
     else:                                                # paged layout
         page_rows = page_tab[lane_ix]                    # [P, Pg]
         lane = dict(ring)
@@ -482,10 +487,11 @@ def _swan_layer_prefill_chunk(lp: Params, p_qk_l, cache_l: Params, cfg, swan,
             view = swa.paged_logical_view(lane, page_rows)
             o = swa.swan_chunk_prefill_attention(q_hat, k_hat, v, view,
                                                  swan, cfg, start, true_len)
-        lane = pc.paged_insert_prefill_chunk(lane, swan, cfg, k_hat, v,
-                                             start, true_len, page_rows,
-                                             k_act=k_act,
-                                             dead=slot >= n_slots)
+        with jax.named_scope("winnow"):
+            lane = pc.paged_insert_prefill_chunk(lane, swan, cfg, k_hat, v,
+                                                 start, true_len, page_rows,
+                                                 k_act=k_act,
+                                                 dead=slot >= n_slots)
         out_l["pool"] = lane["pool"]
         ring_new = {n: lane[n] for n in ("buf_k", "buf_v", "buf_pos")}
     for n in ("buf_k", "buf_v", "buf_pos"):
@@ -602,20 +608,22 @@ def lm_prefill_chunk_batched(p: Params, cfg, tokens: jnp.ndarray,
 
     def body(x, xs):
         lp, cache_l, p_qk_l, k_l = xs
-        h = apply_norm(lp["ln1"], cfg, x)
-        if use_swan:
-            k_eff = k_l if k_req is None else jnp.minimum(k_l, k_req)
-            h, cache_l = _swan_layer_prefill_chunk(
-                lp, p_qk_l, cache_l, cfg, swan, h, slot, start, true_len,
-                positions, k_act=k_eff, page_tab=page_tab,
-                prefix_len=prefix_len, use_pallas=use_pallas,
-                pallas_interpret=pallas_interpret)
-        else:
-            h, cache_l = _dense_layer_prefill_chunk(lp, cache_l, cfg, h,
-                                                    slot, start, positions,
-                                                    prefix_len=prefix_len)
+        with jax.named_scope("attn"):
+            h = apply_norm(lp["ln1"], cfg, x)
+            if use_swan:
+                k_eff = k_l if k_req is None else jnp.minimum(k_l, k_req)
+                h, cache_l = _swan_layer_prefill_chunk(
+                    lp, p_qk_l, cache_l, cfg, swan, h, slot, start,
+                    true_len, positions, k_act=k_eff, page_tab=page_tab,
+                    prefix_len=prefix_len, use_pallas=use_pallas,
+                    pallas_interpret=pallas_interpret)
+            else:
+                h, cache_l = _dense_layer_prefill_chunk(
+                    lp, cache_l, cfg, h, slot, start, positions,
+                    prefix_len=prefix_len)
         x = shard(x + h, "residual")
-        x = shard(_layer_ffn(lp, cfg, x), "residual")
+        with jax.named_scope("mlp"):
+            x = shard(_layer_ffn(lp, cfg, x), "residual")
         return x, cache_l
 
     pq, k_arr = _swan_scan_xs(cfg, swan, projections, use_swan)
@@ -673,18 +681,20 @@ def lm_decode_step(p: Params, cfg, token: jnp.ndarray, pos, caches: Params,
 
     def body(x, xs):
         lp, cache_l, p_qk_l, k_l = xs
-        h = apply_norm(lp["ln1"], cfg, x)
-        if use_swan:
-            k_eff = k_l if k_req is None else jnp.minimum(k_l, k_req)
-            h, cache_l = _swan_layer_decode(lp, p_qk_l, cache_l, cfg, swan,
-                                            h, pos, k_act=k_eff,
-                                            page_tab=page_tab,
-                                            use_pallas=use_pallas,
-                                            pallas_interpret=pallas_interpret)
-        else:
-            h, cache_l = attn.attn_decode_dense(lp["attn"], cfg, h, pos, cache_l)
+        with jax.named_scope("attn"):
+            h = apply_norm(lp["ln1"], cfg, x)
+            if use_swan:
+                k_eff = k_l if k_req is None else jnp.minimum(k_l, k_req)
+                h, cache_l = _swan_layer_decode(
+                    lp, p_qk_l, cache_l, cfg, swan, h, pos, k_act=k_eff,
+                    page_tab=page_tab, use_pallas=use_pallas,
+                    pallas_interpret=pallas_interpret)
+            else:
+                h, cache_l = attn.attn_decode_dense(lp["attn"], cfg, h, pos,
+                                                    cache_l)
         x = x + h
-        x = _layer_ffn(lp, cfg, x)
+        with jax.named_scope("mlp"):
+            x = _layer_ffn(lp, cfg, x)
         return x, cache_l
 
     pq, k_arr = _swan_scan_xs(cfg, swan, projections, use_swan)

@@ -119,12 +119,18 @@ Observability (``repro.obs``): the engine carries a ``metrics`` registry
 (``metrics=False`` swaps in a no-op registry) and an optional ``trace``
 JSONL event sink.  ALL instrumentation lives on the host side of the
 dispatch boundaries — counters/gauges/histograms are plain Python updates
-between jitted calls, trace events are step-indexed (never wall-clocked),
-and nothing observable is threaded into a traced function — so metrics on
-vs off produces identical tokens, identical dispatch counts and identical
-compiled executables (pinned by tests/test_obs_engine.py).  Wall time
-appears only in explicit ``obs.span`` blocks and the ``profile_steps``
-hook that brackets N engine steps with ``jax.profiler`` start/stop.
+between jitted calls, trace events are step-indexed (and stamped with the
+program clock), and nothing observable is threaded into a traced function
+— so metrics on vs off produces identical tokens, identical dispatch
+counts and identical compiled executables (pinned by
+tests/test_obs_engine.py).  Every host phase of ``step()`` is an
+``obs.span`` (``engine.step`` > ``engine.resolve`` > ``engine.sample``,
+``engine.apply``, ``engine.admit``, ``engine.prefill`` >
+``engine.dispatch.chunk``, ``engine.decode`` > ``engine.dispatch.decode``,
+``engine.fetch``, ``engine.gauges``; see docs/observability.md): recorded,
+and annotated in the profiler's trace, only while a ``jax.profiler``
+session records (the ``profile_steps`` hook, or an outside one), a flag
+check otherwise.
 
 Byte accounting — ``cache_report()`` and the ``kv_cache_*`` gauges read
 the SAME ``_cache_bytes()`` source (slab, paged and sharded paths share
@@ -164,7 +170,7 @@ path.  See docs/serving.md.
 from __future__ import annotations
 
 import inspect
-import time
+import math
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence
@@ -178,10 +184,12 @@ from repro.core import hybrid_cache as hc
 from repro.core import paged_cache as pc
 from repro.kernels.dispatch import (pallas_decode_supported,
                                     resolve_interpret, resolve_use_pallas)
+from repro.kernels.swan_decode.swan_decode import paged_decode_grid
 from repro.models import get_model, swan_applicable
 from repro.obs import compile_events
 from repro.obs.metrics import MetricsRegistry, NULL_REGISTRY
-from repro.obs.trace import EventTrace, StepProfiler
+from repro.obs.trace import (EventTrace, StepProfiler, recording, span,
+                             step_span)
 from repro.runtime.page_pool import PagePool, PagePoolExhausted
 from repro.runtime.sampling import sample_token
 from repro.runtime.serve_loop import serve_cache_report
@@ -191,19 +199,14 @@ from repro.sharding.specs import dp_axes, params_pspecs
 
 Params = Dict[str, Any]
 
-# fixed histogram buckets, in ENGINE STEPS (deterministic scheduler time —
-# wall-clock never enters the registry); powers of two to match the
-# engine's bucketing story
+# fixed histogram buckets, in ENGINE STEPS (deterministic scheduler time);
+# powers of two to match the engine's bucketing story
 TTFT_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024)
 GAP_BUCKETS = (1, 2, 3, 4, 6, 8, 12, 16, 24, 32, 48, 64)
 REQ_STEP_BUCKETS = (4, 8, 16, 32, 64, 128, 256, 512, 1024, 2048, 4096)
-# host-side dispatch submit latency in ms, measured WITHOUT a device sync
-# (async dispatch returns immediately once the executable is enqueued, so
-# this captures launch/retrace overhead, not device compute — wall-clock
-# step time lives in serve_loop's serve_step_ms); the kernel label says
-# which implementation backed the hot-path read
-DISPATCH_MS_BUCKETS = (0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 25.0,
-                       50.0, 100.0, 250.0, 1000.0, 4000.0)
+# host block on the decode token transfer, in ms (the engine.resolve span)
+FETCH_MS_BUCKETS = (0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 25.0,
+                    50.0, 100.0, 250.0, 1000.0, 4000.0)
 
 
 @dataclass
@@ -376,6 +379,10 @@ class ServeEngine:
                 "no kernel path (needs transformer-family decode with "
                 "mode='topk' and buffer > 0)")
         self._pallas_interpret = resolve_interpret(None)
+        # stat of the engine.dispatch.* spans: which implementation backed
+        # the hot-path read
+        self._kernel_stat = {"kernel": "pallas" if self.use_pallas
+                             else "xla"}
         k_fill = 0 if self.swan is None else self.swan.k_max
 
         self.prefill_chunk = prefill_chunk
@@ -497,8 +504,9 @@ class ServeEngine:
             if self.use_pallas:
                 kw["use_pallas"] = True
                 kw["pallas_interpret"] = self._pallas_interpret
-            logits, state = self.api.decode_step(p, cfg, token, pos, state,
-                                                 sw, pj, **kw)
+            with jax.named_scope("decode_step"):
+                logits, state = self.api.decode_step(p, cfg, token, pos,
+                                                     state, sw, pj, **kw)
             # device-side greedy sampling: ship back [B] token ids, not
             # [B, V] logits (host fetches logits only for temperature > 0)
             return logits, jnp.argmax(logits, axis=-1).astype(jnp.int32), state
@@ -539,9 +547,10 @@ class ServeEngine:
                 if self.use_pallas:
                     kw["use_pallas"] = True
                     kw["pallas_interpret"] = self._pallas_interpret
-                logits, state = self.api.prefill_chunk(
-                    p, cfg, {"tokens": tokens}, state, slot, start, sw, pj,
-                    true_len=true_len, prefix_len=prefix_len, **kw)
+                with jax.named_scope("chunk_step"):
+                    logits, state = self.api.prefill_chunk(
+                        p, cfg, {"tokens": tokens}, state, slot, start, sw,
+                        pj, true_len=true_len, prefix_len=prefix_len, **kw)
                 # device-side greedy first-token sampling, mirroring
                 # decode_fn: ship back [P] ids; logits rows cross to host
                 # only for lanes that finished a temperature request's
@@ -620,24 +629,18 @@ class ServeEngine:
         # shard count (one chunk + one decode dispatch per step)
         self.dispatches = {"prefill": 0, "chunk": 0, "decode": 0}
 
-    def _obs_dispatch(self, kind: str, dt: float, compiles: int = 0) -> None:
-        """Record one hot-path dispatch: which kernel implementation backed
-        it (pallas vs xla), the host-side submit latency, and any XLA
-        compiles the dispatch triggered (``compiles`` is the
-        ``compile_events.total()`` delta bracketing the call — zero in
+    def _obs_dispatch(self, kind: str, compiles: int = 0) -> None:
+        """Record one hot-path dispatch: whether the Pallas kernels backed
+        it, and any XLA compiles the dispatch triggered (``compiles`` is
+        the ``compile_events.total()`` delta bracketing the call — zero in
         steady state, and zero from the very first request once
-        :meth:`warmup` has run).  No device sync happens here — ``dt``
-        brackets only the async dispatch call."""
-        kernel = "pallas" if self.use_pallas else "xla"
+        :meth:`warmup` has run).  Its host time is the
+        ``engine.dispatch.<kind>`` span."""
         if self.use_pallas:
             self.metrics.counter(
                 "serve_pallas_dispatch_total",
                 "hot-path dispatches backed by the Pallas kernels",
                 kind=kind).inc()
-        self.metrics.histogram(
-            "serve_dispatch_ms", DISPATCH_MS_BUCKETS,
-            "host-side dispatch submit latency (no device sync)",
-            kind=kind, kernel=kernel).observe(dt * 1e3)
         if compiles:
             self.metrics.counter(
                 "serve_compile_total",
@@ -649,6 +652,10 @@ class ServeEngine:
     # ------------------------------------------------------------------
 
     def submit(self, req: Request) -> None:
+        with span("engine.submit", self.step_count, uid=req.uid):
+            self._submit(req)
+
+    def _submit(self, req: Request) -> None:
         if len(req.tokens) + req.max_new_tokens > self.max_seq:
             raise ValueError(
                 f"request {req.uid}: {len(req.tokens)}+{req.max_new_tokens} "
@@ -810,14 +817,15 @@ class ServeEngine:
         per (lane, request, draw-index) triple.  This is the engine's ONLY
         decode-token host-sync point (allowlisted for swanlint SWAN102,
         like the ``_sample`` it calls) — everything upstream of it stays
-        async."""
+        async.  The ``engine.sample`` span starts where the wait ends."""
         greedy = np.asarray(pending.greedy)
         rows = (np.asarray(pending.rows) if pending.rows is not None
                 else None)
         temp = pending.temp
-        return [int(greedy[lane]) if req.temperature <= 0.0
-                else self._sample(rows[temp.index(lane)], req, draw)
-                for lane, req, draw in pending.picks]
+        with span("engine.sample", self.step_count):
+            return [int(greedy[lane]) if req.temperature <= 0.0
+                    else self._sample(rows[temp.index(lane)], req, draw)
+                    for lane, req, draw in pending.picks]
 
     def _lane_tokens(self, logits, greedy, picks) -> List[int]:
         """Synchronous fetch: start the transfer and resolve it
@@ -837,18 +845,22 @@ class ServeEngine:
         pending, self._pending = self._pending, None
         if pending is None:
             return
-        t0 = time.perf_counter()
-        toks = self._resolve_tokens(pending)
-        self.metrics.histogram(
-            "serve_token_fetch_ms", DISPATCH_MS_BUCKETS,
-            "host block on the decode token transfer",
-            mode="async").observe((time.perf_counter() - t0) * 1e3)
         step_now = self.step_count
+        with span("engine.resolve", step_now, timed=True) as sp:
+            toks = self._resolve_tokens(pending)
+        self._obs_fetch("async", sp.ms)
         self.step_count = pending.step      # stamp at the dispatch step
         try:
-            self._apply_decode_tokens(pending.lanes, toks)
+            with span("engine.apply", step_now):
+                self._apply_decode_tokens(pending.lanes, toks)
         finally:
             self.step_count = step_now
+
+    def _obs_fetch(self, mode: str, ms: float) -> None:
+        self.metrics.histogram(
+            "serve_token_fetch_ms", FETCH_MS_BUCKETS,
+            "host block on the decode token transfer",
+            mode=mode).observe(ms)
 
     def _apply_decode_tokens(self, lanes, toks) -> None:
         """Apply one decode step's sampled tokens to the scheduler state:
@@ -979,9 +991,11 @@ class ServeEngine:
         toks = np.zeros((pad_len,), np.int32)
         toks[:plen] = np.asarray(req.tokens, np.int32)
         c0 = compile_events.total()
-        logits, state1 = self._prefill(self.params, {"tokens": toks[None]},
-                                       state1, np.int32(k_req),
-                                       np.int32(plen))
+        with span("engine.dispatch.prefill", self.step_count, uid=req.uid,
+                  stats=self._kernel_stat):
+            logits, state1 = self._prefill(
+                self.params, {"tokens": toks[None]}, state1,
+                np.int32(k_req), np.int32(plen))
         dc = compile_events.total() - c0
         if dc:
             self.metrics.counter(
@@ -1294,12 +1308,12 @@ class ServeEngine:
             page_tab = np.zeros((), np.int32)           # unused operand
             prefix = min(self._pow2(int(start_v.max()) + C), self.max_seq)
         c0 = compile_events.total()
-        t0 = time.perf_counter()
-        logits, greedy, self.state = self._chunk_call(
-            self.params, toks, self.state, slot_v, start_v, k_v, tlen_v,
-            page_tab, prefix=prefix)
-        self._obs_dispatch("chunk", time.perf_counter() - t0,
-                           compiles=compile_events.total() - c0)
+        with span("engine.dispatch.chunk", self.step_count,
+                  stats=self._kernel_stat):
+            logits, greedy, self.state = self._chunk_call(
+                self.params, toks, self.state, slot_v, start_v, k_v, tlen_v,
+                page_tab, prefix=prefix)
+        self._obs_dispatch("chunk", compiles=compile_events.total() - c0)
         self.dispatches["chunk"] += 1
         self.metrics.counter("serve_dispatches_total",
                              "jitted dispatches by kind", kind="chunk").inc()
@@ -1318,8 +1332,10 @@ class ServeEngine:
                 fins.append((lane, i))
         if not fins:
             return
-        firsts = self._lane_tokens(
-            logits, greedy, [(lane, self.slots[i].req, 0) for lane, i in fins])
+        with span("engine.prefill.tokens", self.step_count):
+            firsts = self._lane_tokens(
+                logits, greedy,
+                [(lane, self.slots[i].req, 0) for lane, i in fins])
         for (lane, i), first in zip(fins, firsts):
             s = self.slots[i]
             s.state = "decoding"
@@ -1413,15 +1429,34 @@ class ServeEngine:
         if self._profiler is not None:
             self._profiler.step_start(self.step_count)
         n_done0 = len(self.completions)
-        # the previous step's decode tokens land BEFORE any scheduling
-        # decision, so admission/chunking see the same world as sync mode
-        self._resolve_pending()
-        self._admit_pending()
-        if self.prefill_chunk is not None:
-            self._advance_prefills()
-        active = [i for i, s in enumerate(self.slots)
-                  if s is not None and s.state == "decoding"]
-        if active:
+        n = self.step_count
+        with step_span("engine.step", n):
+            # the previous step's decode tokens land BEFORE any scheduling
+            # decision, so admission/chunking see the same world as sync
+            # mode
+            self._resolve_pending()
+            with span("engine.admit", n):
+                self._admit_pending()
+            if self.prefill_chunk is not None:
+                with span("engine.prefill", n):
+                    self._advance_prefills()
+            active = [i for i, s in enumerate(self.slots)
+                      if s is not None and s.state == "decoding"]
+            if active:
+                self._decode_active(active)
+            self.step_count += 1
+            with span("engine.gauges", n):
+                self._sample_gauges()
+        if self._profiler is not None:
+            self._profiler.step_end(self.step_count)
+        return len(self.completions) - n_done0
+
+    def _decode_active(self, active: List[int]) -> None:
+        """One batched decode dispatch over the DECODING lanes, then either
+        start the token fetch (``async_fetch``) or resolve and apply it."""
+        n = self.step_count
+        with span("engine.decode", n):
+            stats = self._kernel_stat
             if self.paged:
                 # grow each sequence's page mapping to cover the token its
                 # decode step is about to winnow (grows the pool, or raises
@@ -1431,47 +1466,55 @@ class ServeEngine:
                         int(self.slot_pos[i])))
                 # ship only a power-of-two bucket of logical pages: the
                 # attention gather then materialises a view sized by LIVE
-                # pages, not max_seq (transient memory follows tokens too);
-                # one decode executable per bucket — O(log max_pages) total.
-                # The upload itself is cached (dirty-flag) in _device_table.
-                page_tab = self._device_table(self._page_bucket(active))
+                # pages, not max_seq (transient memory follows tokens
+                # too); one decode executable per bucket — O(log
+                # max_pages) total.  The upload itself is cached
+                # (dirty-flag) in _device_table.
+                width = self._page_bucket(active)
+                page_tab = self._device_table(width)
+                if recording():
+                    stats = self._grid_stats(active, width)
             else:
                 page_tab = np.zeros((), np.int32)       # unused operand
             c0 = compile_events.total()
-            t0 = time.perf_counter()
-            logits, greedy, self.state = self._decode(
-                self.params, self.next_tok, self.slot_pos, self.slot_k,
-                page_tab, self.state)
-            self._obs_dispatch("decode", time.perf_counter() - t0,
-                               compiles=compile_events.total() - c0)
+            with span("engine.dispatch.decode", n, stats=stats):
+                logits, greedy, self.state = self._decode(
+                    self.params, self.next_tok, self.slot_pos, self.slot_k,
+                    page_tab, self.state)
+            self._obs_dispatch("decode", compiles=compile_events.total() - c0)
             self.dispatches["decode"] += 1
             self.metrics.counter("serve_dispatches_total",
                                  "jitted dispatches by kind",
                                  kind="decode").inc()
             if self.trace is not None:
-                self.trace.emit("decode_dispatch", step=self.step_count,
-                                lanes=len(active))
+                self.trace.emit("decode_dispatch", step=n, lanes=len(active))
             picks = [(i, self.slots[i].req, len(self.slots[i].generated))
                      for i in active]
-            if self.async_fetch:
-                # start the device->host copy now, consume it at the top
-                # of the NEXT step — the host does a full step of
-                # scheduling work while the transfer is in flight
+        if self.async_fetch:
+            # start the device->host copy now, consume it at the top of
+            # the NEXT step — the host does a full step of scheduling work
+            # while the transfer is in flight
+            with span("engine.fetch", n):
                 self._pending = self._start_fetch(
-                    logits, greedy, picks, self.step_count, lanes=active)
-            else:
-                t0 = time.perf_counter()
-                toks = self._lane_tokens(logits, greedy, picks)
-                self.metrics.histogram(
-                    "serve_token_fetch_ms", DISPATCH_MS_BUCKETS,
-                    "host block on the decode token transfer",
-                    mode="sync").observe((time.perf_counter() - t0) * 1e3)
-                self._apply_decode_tokens(active, toks)
-        self.step_count += 1
-        self._sample_gauges()
-        if self._profiler is not None:
-            self._profiler.step_end(self.step_count)
-        return len(self.completions) - n_done0
+                    logits, greedy, picks, n, lanes=active)
+            return
+        with span("engine.resolve", n, timed=True) as sp:
+            toks = self._lane_tokens(logits, greedy, picks)
+        self._obs_fetch("sync", sp.ms)
+        with span("engine.apply", n):
+            self._apply_decode_tokens(active, toks)
+
+    def _grid_stats(self, active: List[int], width: int) -> Dict[str, Any]:
+        """Occupancy of the paged decode kernel's grid, for the
+        ``engine.dispatch.decode`` span: ``grid_pages`` (slot, page) cells
+        it iterates on every shard (read from the kernel's own grid for
+        one KV head over the shipped page bucket) and ``live_pages`` of
+        them that hold a decoding lane's winnowed tokens."""
+        ps = self.pool.page_size
+        live = sum(-(-self._sparse_tokens(int(self.slot_pos[i])) // ps)
+                   for i in active)
+        grid = self.dp * math.prod(paged_decode_grid(self.n_local, 1, width))
+        return {**self._kernel_stat, "grid_pages": grid, "live_pages": live}
 
     def profile_steps(self, n_steps: int, logdir: str) -> None:
         """Capture one ``jax.profiler`` trace spanning the next

@@ -57,6 +57,7 @@ import numpy as np
 
 from repro.core import paged_cache as pc
 from repro.obs import compile_events
+from repro.obs.trace import span
 
 _COMPILE_HELP = "XLA backend compiles by phase (warmup vs mid-serve)"
 
@@ -236,7 +237,9 @@ def warmup_engine(eng, max_prompt_len: Optional[int] = None
     def timed(item: WarmupItem, fn) -> Any:
         c0 = compile_events.total()
         t0 = time.perf_counter()
-        out = fn()
+        with span("warmup.item", eng.step_count,
+                  stats={"family": item.kind, "shape": item.detail}):
+            out = fn()
         dc = compile_events.total() - c0
         rows.append({"kind": item.kind, "detail": item.detail,
                      "compiles": dc,

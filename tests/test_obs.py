@@ -7,7 +7,10 @@ import math
 import pytest
 
 from repro.obs import (EventTrace, MetricsRegistry, NULL_REGISTRY,
-                       NullRegistry, StepProfiler, parse_prometheus, span)
+                       NullRegistry, StepProfiler, clear_spans,
+                       compile_events, parse_prometheus, profile_options,
+                       record_spans, recorded_spans, recording, span,
+                       step_span)
 
 
 # ---------------------------------------------------------------------------
@@ -200,6 +203,10 @@ def test_event_trace_file_round_trip(tmp_path):
         tr.emit("token", step=2, uid="a", index=0, token=42)
         assert tr.events == []            # keep defaults to False with path
     back = EventTrace.read(path)
+    # every event carries the program clock at emit time, in order
+    stamps = [e.pop("t_ns") for e in back]
+    assert all(isinstance(t, int) for t in stamps)
+    assert stamps == sorted(stamps)
     assert back == [
         {"event": "submit", "step": 0, "uid": "a", "prompt_len": 7},
         {"event": "token", "step": 2, "uid": "a", "index": 0, "token": 42},
@@ -207,10 +214,10 @@ def test_event_trace_file_round_trip(tmp_path):
 
 
 def test_span_emits_wall_ms_and_none_is_noop():
-    with span(None, "nothing"):
+    with span("nothing"):
         pass                              # must not raise
     tr = EventTrace()
-    with span(tr, "prefill", step=5, uid="r0"):
+    with span("prefill", step=5, uid="r0", trace=tr):
         pass
     (ev,) = tr.select("span")
     assert ev["name"] == "prefill" and ev["uid"] == "r0" and ev["step"] == 5
@@ -232,3 +239,132 @@ def test_step_profiler_brackets_exactly_n_steps():
     assert tr.select("profile_stop")[0]["step"] == 3
     with pytest.raises(ValueError):
         StepProfiler("/tmp/prof", 0)
+
+
+def test_no_session_records_nothing():
+    """Outside a profiler session a span is one shared no-op object and
+    nothing is buffered; a timed span still reads the clock."""
+    clear_spans()
+    assert not recording()
+    assert span("a", 1) is span("b", 2, uid="r") is step_span("s", 3)
+    with span("engine.admit", 1):
+        pass
+    with span("engine.resolve", 1, timed=True) as sp:
+        pass
+    assert sp.ms >= 0.0
+    assert recorded_spans() == []
+
+
+def test_recorded_spans_nest_with_parents():
+    clear_spans()
+    record_spans(True)
+    try:
+        with step_span("engine.step", 4):
+            with span("engine.decode", 4):
+                with span("engine.dispatch.decode", 4,
+                          stats={"grid_pages": 8, "live_pages": 3}):
+                    pass
+            with span("engine.submit", 4, uid="r9"):
+                pass
+    finally:
+        record_spans(False)
+    by = {s.name: s for s in recorded_spans()}
+    assert [s.name for s in recorded_spans()] == [
+        "engine.dispatch.decode", "engine.decode", "engine.submit",
+        "engine.step"]
+    assert by["engine.step"].parent == -1
+    assert by["engine.decode"].parent == by["engine.step"].id
+    assert by["engine.submit"].parent == by["engine.step"].id
+    assert by["engine.dispatch.decode"].parent == by["engine.decode"].id
+    assert by["engine.submit"].uid == "r9"
+    assert by["engine.dispatch.decode"].stats["live_pages"] == 3
+    step = by["engine.step"]
+    assert all(step.t0_ns <= s.t0_ns <= s.t1_ns <= step.t1_ns
+               for s in by.values())
+    assert {s.step for s in by.values()} == {4}
+    clear_spans()
+
+
+def test_span_lands_in_profiler_host_plane(tmp_path):
+    """In a profiled block every span is an annotation on the profiler's
+    host plane, and the step annotation's ``host_ns`` maps each buffered
+    span onto it to within the span's own duration."""
+    import glob
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    clear_spans()
+    jax.profiler.start_trace(str(tmp_path), profiler_options=profile_options())
+    try:
+        for step in range(2):
+            with step_span("engine.step", step):
+                with span("engine.resolve", step, timed=True):
+                    jnp.ones(64).sum().block_until_ready()
+                    time.sleep(0.003)
+                with span("engine.submit", step, uid=f"r{step}"):
+                    time.sleep(0.003)
+    finally:
+        jax.profiler.stop_trace()
+    assert not recording()
+    mine = recorded_spans()
+    clear_spans()
+    assert len(mine) == 6
+    (pb,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    pd = jax.profiler.ProfileData.from_file(pb)
+    events = [(e.name, int(e.start_ns), int(e.duration_ns), dict(e.stats))
+              for p in pd.planes if p.name.startswith("/host:")
+              for ln in p.lines for e in ln.events
+              if e.name.startswith("engine.")]
+    steps = [e for e in events if e[0] == "engine.step"]
+    assert sorted(e[3]["step_num"] for e in steps) == [0, 1]
+    offsets = {e[3]["host_ns"] - e[1] for e in steps}
+    assert max(offsets) - min(offsets) < 1_000_000      # one clock offset
+    off = min(offsets)
+    assert {e[3].get("uid") for e in events
+            if e[0] == "engine.submit"} == {"r0", "r1"}
+    for s in mine:
+        dur = s.t1_ns - s.t0_ns
+        ev = min((e for e in events if e[0] == s.name),
+                 key=lambda e: abs(e[1] + off - s.t0_ns))
+        assert abs(ev[1] + off - s.t0_ns) < dur
+        assert abs(ev[1] + ev[2] + off - s.t1_ns) < dur
+
+
+def test_profile_options_match_the_benchmark():
+    opts = profile_options()
+    assert opts.python_tracer_level == 0 and opts.host_tracer_level == 1
+
+
+def test_compile_timeline_folds_nested_and_splits_cache_loads():
+    """A trace event that ended inside a later one of its group (an inner
+    jit traced while the outer one traced) is folded into it; a backend
+    compile the persistent cache answered is a cache load; phase_seconds
+    reads a window of receipt times."""
+    import time
+    trace_ev = "/jax/core/compile/jaxpr_trace_duration"
+    lower_ev = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+    compile_ev = "/jax/core/compile/backend_compile_duration"
+    t0 = time.perf_counter_ns()
+    time.sleep(0.002)
+    compile_events._listener(trace_ev, 0.001)       # the inner jit's trace
+    time.sleep(0.002)
+    compile_events._listener(trace_ev, 0.05)        # the outer trace
+    time.sleep(0.002)
+    compile_events._listener(lower_ev, 0.001)
+    total0 = compile_events.total()
+    compile_events._cache_listener("/jax/compilation_cache/cache_hits")
+    compile_events._listener(compile_ev, 0.0005)
+    time.sleep(0.002)
+    compile_events._listener(compile_ev, 0.001)
+    t1 = time.perf_counter_ns()
+    mine = [(p, s) for p, t, s in compile_events.timeline() if t0 < t <= t1]
+    assert mine == [("trace", 0.05), ("lower", 0.001),
+                    ("cache_load", 0.0005), ("compile", 0.001)]
+    assert compile_events.total() == total0 + 2
+    assert compile_events.phase_seconds(("trace", "lower"), t0, t1) \
+        == pytest.approx(0.051)
+    assert compile_events.phase_seconds(("compile", "cache_load"), t0, t1) \
+        == pytest.approx(0.0015)
+    assert compile_events.phase_seconds(("compile",), t1) == 0.0

@@ -12,7 +12,9 @@ import pytest
 from repro.configs import SwanConfig, get_smoke_config
 from repro.launch.io import make_batch
 from repro.models import get_model
-from repro.obs import EventTrace, MetricsRegistry, parse_prometheus
+from repro.obs import (EventTrace, MetricsRegistry, clear_spans,
+                       compile_events, parse_prometheus, record_spans,
+                       recorded_spans)
 from repro.runtime.serve_engine import Request, ServeEngine
 from repro.runtime.serve_loop import ServeSession, calibrate_swan
 
@@ -92,6 +94,151 @@ def test_metrics_on_vs_off_token_and_dispatch_identity(setup, obs_run):
     # off really is off: the null registry never accumulates state
     assert not off.metrics.enabled
     assert off.metrics.snapshot() == {"metrics": {}}
+    # spans recorded vs not: the same tokens, dispatches and steps
+    clear_spans()
+    record_spans(True)
+    try:
+        spanned = ServeEngine(cfg, absorbed, swan=_swan(), projections=pj,
+                              metrics=False, **_ENGINE_KW)
+        sp_comps = spanned.run(_mixed_trace(cfg))
+    finally:
+        record_spans(False)
+    spans = recorded_spans()
+    clear_spans()
+    assert {c.uid: c.tokens for c in sp_comps} \
+        == {c.uid: c.tokens for c in on_comps}
+    assert dict(spanned.dispatches) == dict(on.dispatches)
+    assert spanned.step_count == on.step_count
+    names = [s.name for s in spans]
+    assert names.count("engine.step") == on.step_count
+    assert names.count("engine.dispatch.decode") == on.dispatches["decode"]
+    assert names.count("engine.dispatch.chunk") == on.dispatches["chunk"]
+    assert names.count("engine.submit") == len(_SPEC)
+
+
+def test_step_children_cover_the_step(setup):
+    """Every span of a step nests under its ``engine.step``; the named
+    phases follow one another in the order ``step()`` runs them."""
+    cfg, api, params, absorbed, pj = setup
+    eng = ServeEngine(cfg, absorbed, swan=_swan(), projections=pj,
+                      metrics=False, async_fetch=True, **_ENGINE_KW)
+    for r in _mixed_trace(cfg):
+        eng.submit(r)
+    clear_spans()
+    record_spans(True)
+    try:
+        while not eng.done:
+            eng.step()
+    finally:
+        record_spans(False)
+    spans = recorded_spans()
+    clear_spans()
+    steps = {s.id: s for s in spans if s.name == "engine.step"}
+    assert len(steps) == eng.step_count
+    order = ["engine.resolve", "engine.apply", "engine.admit",
+             "engine.prefill", "engine.decode", "engine.fetch",
+             "engine.gauges"]
+    for st in steps.values():
+        kids = sorted((s for s in spans if s.parent == st.id),
+                      key=lambda s: s.t0_ns)
+        assert [k.name for k in kids] == [n for n in order
+                                          if n in {k.name for k in kids}]
+        assert all(k.step == st.step for k in kids)
+        assert all(st.t0_ns <= k.t0_ns <= k.t1_ns <= st.t1_ns for k in kids)
+    by_id = {s.id: s for s in spans}
+    resolves = [s for s in spans if s.name == "engine.resolve"]
+    assert resolves
+    for r in resolves:
+        # the wait ends where sampling starts, inside the resolve
+        (smp,) = [s for s in spans if s.parent == r.id]
+        assert smp.name == "engine.sample"
+        assert r.t0_ns <= smp.t0_ns <= smp.t1_ns <= r.t1_ns
+    for s in spans:
+        if s.name.startswith("engine.dispatch."):
+            assert by_id[s.parent].name == {
+                "chunk": "engine.prefill",
+                "decode": "engine.decode"}[s.name.rsplit(".", 1)[1]]
+
+
+def test_decode_grid_counts_match_hand_count(setup):
+    """``grid_pages`` / ``live_pages`` on the decode dispatch span: two
+    monolithic admissions of 40 and 10 prompt tokens, ring 4, pages of
+    16.  The first decode winnows positions up to 40 - 4 = 36 (37 tokens,
+    3 pages) and 10 - 4 = 6 (7 tokens, 1 page): 4 live pages; the bucket
+    covers the longer lane's 3 pages as 4, over 2 slots: 8 cells."""
+    cfg, api, params, absorbed, pj = setup
+    eng = ServeEngine(cfg, absorbed, swan=_swan(), projections=pj,
+                      max_seq=64, n_slots=2, paged=True, page_size=PAGE,
+                      metrics=False)
+    eng.submit(Request(uid="a", tokens=_prompt(cfg, 40, seed=1),
+                       max_new_tokens=8))
+    eng.submit(Request(uid="b", tokens=_prompt(cfg, 10, seed=2),
+                       max_new_tokens=8))
+    clear_spans()
+    record_spans(True)
+    try:
+        eng.step()
+        # the next decode: positions 41 and 11 winnow 38 and 8 tokens
+        eng.step()
+    finally:
+        record_spans(False)
+    dec = [s for s in recorded_spans() if s.name == "engine.dispatch.decode"]
+    clear_spans()
+    assert [(s.stats["grid_pages"], s.stats["live_pages"]) for s in dec] \
+        == [(8, 4), (8, 4)]
+    assert all(s.stats["kernel"] == "xla" for s in dec)
+
+
+def test_decode_grid_count_follows_the_kernel_grid(setup, monkeypatch):
+    """``grid_pages`` is read from the paged decode kernel's own grid
+    function, so a kernel whose grid changes changes the count with it."""
+    import repro.runtime.serve_engine as se
+    cfg, api, params, absorbed, pj = setup
+    eng = ServeEngine(cfg, absorbed, swan=_swan(), projections=pj,
+                      max_seq=64, n_slots=2, paged=True, page_size=PAGE,
+                      metrics=False)
+    eng.submit(Request(uid="a", tokens=_prompt(cfg, 40, seed=1),
+                       max_new_tokens=4))
+    monkeypatch.setattr(se, "paged_decode_grid",
+                        lambda n_slots, n_kv, n_pages: (1, n_kv, n_pages))
+    clear_spans()
+    record_spans(True)
+    try:
+        eng.step()
+    finally:
+        record_spans(False)
+    (dec,) = [s for s in recorded_spans()
+              if s.name == "engine.dispatch.decode"]
+    clear_spans()
+    # one lane's 3 live pages in a bucket of 4, over a grid of one slot
+    assert (dec.stats["grid_pages"], dec.stats["live_pages"]) == (4, 3)
+
+
+def test_compile_timeline_within_warmup_wall(setup):
+    """The tracing, lowering, compile and cache-load seconds received
+    during a warmup sum to at most its wall time: nested events are not
+    counted twice."""
+    import time
+    cfg, api, params, absorbed, pj = setup
+    eng = ServeEngine(cfg, absorbed, swan=_swan(), projections=pj,
+                      metrics=False, **_ENGINE_KW)
+    clear_spans()
+    record_spans(True)
+    try:
+        t0 = time.perf_counter_ns()
+        report = eng.warmup(max_prompt_len=16)
+        t1 = time.perf_counter_ns()
+    finally:
+        record_spans(False)
+    items = [s for s in recorded_spans() if s.name == "warmup.item"]
+    clear_spans()
+    assert [(s.stats["family"], s.stats["shape"]) for s in items] \
+        == [(r["kind"], r["detail"]) for r in report["items"]]
+    lower = compile_events.phase_seconds(("trace", "lower"), t0, t1)
+    compiled = compile_events.phase_seconds(("compile", "cache_load"), t0,
+                                            t1)
+    assert lower > 0 and compiled > 0
+    assert lower + compiled <= (t1 - t0) * 1e-9
 
 
 # ---------------------------------------------------------------------------

@@ -203,12 +203,25 @@ def test_engine_pallas_token_identity(tiny_serve, paged):
     swan = SwanConfig(k_max=8, buffer=4, mode="topk")
     kw = dict(paged=True, page_size=16) if paged else {}
 
+    from repro.obs import clear_spans, record_spans, recorded_spans
+
     def run(use_pallas):
         eng = ServeEngine(cfg, absorbed, swan=swan, projections=pj,
                           max_seq=64, n_slots=3, prefill_chunk=8,
                           prefill_slots=2, use_pallas=use_pallas, **kw)
-        comps = eng.run(_requests(cfg, make_batch))
+        clear_spans()
+        record_spans(True)
+        try:
+            comps = eng.run(_requests(cfg, make_batch))
+        finally:
+            record_spans(False)
+        eng.spans = recorded_spans()
+        clear_spans()
         return {c.uid: c.tokens for c in comps}, eng
+
+    def dispatch_spans(eng, kind, kernel):
+        return [s for s in eng.spans if s.name == f"engine.dispatch.{kind}"
+                and s.stats["kernel"] == kernel]
 
     t_ref, e_ref = run(False)
     t_pal, e_pal = run(True)
@@ -220,14 +233,12 @@ def test_engine_pallas_token_identity(tiny_serve, paged):
     for kind in ("decode", "chunk"):
         assert e_pal.metrics.value("serve_pallas_dispatch_total",
                                    kind=kind) == e_pal.dispatches[kind]
-        h = e_pal.metrics.get("serve_dispatch_ms", kind=kind,
-                              kernel="pallas")
-        assert h is not None and h.count == e_pal.dispatches[kind]
+        assert len(dispatch_spans(e_pal, kind, "pallas")) \
+            == e_pal.dispatches[kind] > 0
         assert e_ref.metrics.value("serve_pallas_dispatch_total",
                                    kind=kind) == 0
-        h_ref = e_ref.metrics.get("serve_dispatch_ms", kind=kind,
-                                  kernel="xla")
-        assert h_ref is not None and h_ref.count == e_ref.dispatches[kind]
+        assert len(dispatch_spans(e_ref, kind, "xla")) \
+            == e_ref.dispatches[kind] > 0
 
 
 def test_engine_use_pallas_rejects_non_kernel_path(tiny_serve):
