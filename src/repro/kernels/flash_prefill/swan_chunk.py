@@ -4,11 +4,13 @@ The chunked-prefill attention (`swan_chunk_prefill_attention`) splits per
 query into [winnowed sparse prefix ‖ ring ‖ chunk]; the sparse-prefix part
 is the bandwidth-bound bulk read this kernel fuses.  Each grid step DMAs
 one packed tile (vals [BS,k] + idx int8, optionally int8 vals + f32
-scales), expands it ONCE in VMEM via the same one-hot fori-loop as the
-decode kernel, and runs all Q = S_chunk·G chunk queries against it through
-two MXU matmuls with online-softmax scratch carried across tiles — the
-multi-query analogue of ``swan_decode``.  The pure-JAX fallback
-(`_sparse_stats_bulk`) expands into an HBM transient instead.
+scales), expands both sides ONCE in VMEM with the decode kernel's
+loop-free ``expand_packed`` (one broadcast compare against a lane iota,
+no cross-lane reduction), and runs all Q = S_chunk·G chunk queries
+against them through two MXU matmuls with online-softmax scratch carried
+across tiles — the multi-query analogue of ``swan_decode``, which gathers
+q instead of expanding K because it has only G query rows.  The pure-JAX
+fallback (`_sparse_stats_bulk`) expands into an HBM transient instead.
 
 Outputs are MERGEABLE partial stats (m_safe [B,Kv,Q], l [B,Kv,Q],
 o_unnorm [B,Kv,Q,dh], all f32): the dense [ring ‖ chunk] side and the
@@ -39,6 +41,11 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.swan_decode.swan_decode import (
     NEG_INF, SMEM_WHOLE, expand_packed, kernel_name, scale_spec,
     softmax_scratch, unit_axis)
+
+# The expansion's [BS, k, dh] compare lives in VMEM: a 256-row slab tile
+# against Q = 2048 query rows needs about 24 MiB, above the compiler's
+# default 16-MiB scope (a v5e core has 128 MiB).
+VMEM_LIMIT_BYTES = 48 * 2**20
 
 
 def _chunk_stats_body(meta_ref, q_ref, kv_ref, ki_ref, vv_ref, vi_ref,
@@ -137,10 +144,12 @@ def _chunk_call(kernel, q, tiles, scales, sp_len, tile_map, lane_map, *,
     grid = (B, Kv, n_sblocks)
     operands = [meta, q, *tiles, *map(unit_axis, scales)]
     name = kernel_name(name, bool(scales))
+    params = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
     if prefetch is None:
         call = pl.pallas_call(kernel, grid=grid, in_specs=specs,
                               out_specs=out_specs, out_shape=out_shape,
                               scratch_shapes=scratch, name=name,
+                              compiler_params=params,
                               interpret=resolve_interpret(interpret))
     else:
         call = pl.pallas_call(
@@ -148,6 +157,7 @@ def _chunk_call(kernel, q, tiles, scales, sp_len, tile_map, lane_map, *,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1, grid=grid, in_specs=specs,
                 out_specs=out_specs, scratch_shapes=scratch),
+            compiler_params=params,
             interpret=resolve_interpret(interpret))
         operands = [prefetch] + operands
     ml, o = call(*operands)

@@ -3,10 +3,22 @@
 The kernel consumes the *compressed* cache directly (paper's
 "decompression-free" claim, TPU-native): each grid step DMAs one packed
 sparse tile (vals [BS,k] + idx [BS,k] int8, optionally int8 vals + f32
-scales) from HBM into VMEM, expands it in VMEM via a one-hot fori-loop
-(never materialising a dense cache in HBM), and feeds two MXU matmuls
-(scores, weighted values) through a flash-style online-softmax accumulator
-held in VMEM scratch.  The final grid step folds in the dense ring buffer.
+scales) from HBM into VMEM and reads it there, never materialising a dense
+cache in HBM.  The K side is never expanded: the scores gather each query
+row along lanes at the tile's indices (:func:`gather_scores`).  The V side
+is expanded by one broadcast compare of the indices against a lane iota
+(:func:`expand_packed`) and feeds the weighted-value MXU matmul.  Both go
+through a flash-style online-softmax accumulator held in VMEM scratch.
+The final grid step folds in the dense ring buffer.
+
+Neither reader loops over the packed columns: Mosaic lowers no dynamic
+lane slice, so reading column ``j`` takes a masked cross-lane reduction,
+and inside a carried loop the 2k reductions of a tile run one after
+another (14.7 us per (lane, KV head, page) cell on a v5e, whatever the
+tile's bytes).  Decode has G <= 8 query rows, so gathering q (a lane
+permute per vreg and query row) is cheaper than expanding K for an MXU
+matmul; the chunk kernel, with Q = 256·G rows, expands both sides once
+and keeps its two matmuls.
 
 Grid: (B, Kv, S/BS) — the sequence axis iterates innermost so the scratch
 accumulators carry across sparse tiles.
@@ -20,9 +32,8 @@ fetches one ``(1, BS)`` scale row of its own head; the scales are applied
 to the score and probability rows after the matmuls, where they are
 lane-major already.
 On a slab cache that makes a quantized ``block_s`` a multiple of 128 (or
-all of S); a page is always a whole block.  The expansion extracts packed
-column ``j`` with a masked lane reduction, since Mosaic lowers no dynamic
-lane slice.  Whether a shape fits VMEM is decided by the compile
+all of S); a page is always a whole block.  Whether a shape fits VMEM is
+decided by the compile
 (``tests/test_tpu_compile.py`` compiles every serve kernel for a v5e at
 OLMo-1B widths); ``precheck`` only mirrors the block rules.
 """
@@ -78,21 +89,50 @@ def precheck(*, S: int, k_max: int, dh: int, block_s: int = 256,
 
 def expand_packed(vals, idx, dh: int):
     """One-hot expansion in VMEM: packed [BS,k] vals (f32) + idx -> dense
-    [BS,dh] f32.  Column ``j`` is read by a masked lane sum (exact: one
-    non-zero term), the form Mosaic lowers inside a rolled loop."""
+    [BS,dh] f32, loop-free.  The indices, as ``[BS, k, 1]``, are compared
+    once against a lane iota ``[1, 1, dh]``; a chain of selects over the
+    k axis's 8-row sublane groups keeps the hit value, and a sublane sum
+    folds the last 8.  Exact: a row's indices are distinct (``topk_pack``
+    sorts a full iota), so an output element has at most one hit; an
+    index outside ``[0, dh)`` matches no lane."""
     bs, k_max = vals.shape
-    lane_k = jax.lax.broadcasted_iota(jnp.int32, (bs, k_max), 1)
-    lane_d = jax.lax.broadcasted_iota(jnp.int32, (bs, dh), 1)
-    idx = idx.astype(jnp.float32)
+    idx = idx.astype(jnp.int32)
+    pad = -k_max % SUBLANE
+    if pad:                           # zero values at an index none matches
+        vals = jnp.concatenate([vals, jnp.zeros((bs, pad), jnp.float32)], 1)
+        idx = jnp.concatenate([idx, jnp.full((bs, pad), -1, jnp.int32)], 1)
+    idx, vals = idx[:, :, None], vals[:, :, None]
+    lane_d = jax.lax.broadcasted_iota(jnp.int32, (bs, SUBLANE, dh), 2)
+    out = 0.0
+    for j in range(0, k_max + pad, SUBLANE):
+        group = functools.partial(jax.lax.slice_in_dim, start_index=j,
+                                  limit_index=j + SUBLANE, axis=1)
+        out = jnp.where(group(idx) == lane_d, group(vals), out)
+    return out.sum(axis=1)
 
-    def body(j, acc):
-        pick = lane_k == j
-        v = jnp.sum(jnp.where(pick, vals, 0.0), axis=1, keepdims=True)
-        i = jnp.sum(jnp.where(pick, idx, 0.0), axis=1, keepdims=True)
-        return acc + jnp.where(lane_d == i.astype(jnp.int32), v, 0.0)
 
-    return jax.lax.fori_loop(0, k_max, body,
-                             jnp.zeros((bs, dh), jnp.float32))
+def gather_scores(q, vals, idx):
+    """Scores of G query rows against a packed tile, never expanded:
+    ``s[g, t] = sum_j vals[t, j] * q[g, idx[t, j]]`` (f32 [G, BS]).  Row
+    ``g`` of ``q`` is gathered along lanes at the tile's indices, times the
+    values, summed over the tile's lanes.  The tile is padded to ``dh``
+    lanes with zero values, and an index outside ``[0, dh)`` (a trash-page
+    tile holds whatever was last written there) reads lane 0: the gather
+    is promised in bounds, and the ``sp_len`` mask drops such rows."""
+    bs, k_max = vals.shape
+    G, dh = q.shape
+    idx = idx.astype(jnp.int32)
+    idx = jnp.where((idx >= 0) & (idx < dh), idx, 0)
+    if k_max < dh:
+        vals = jnp.concatenate(
+            [vals, jnp.zeros((bs, dh - k_max), jnp.float32)], axis=1)
+        idx = jnp.concatenate(
+            [idx, jnp.zeros((bs, dh - k_max), jnp.int32)], axis=1)
+    cols = [jnp.sum(vals * jnp.take_along_axis(
+                jnp.broadcast_to(q[g:g + 1], (bs, dh)), idx, axis=1,
+                mode="promise_in_bounds"), axis=1, keepdims=True)
+            for g in range(G)]                                 # G x [BS, 1]
+    return jnp.concatenate(cols, axis=1).T
 
 
 def _swan_decode_body(meta_ref, q_ref, kv_ref, ki_ref, vv_ref, vi_ref,
@@ -114,13 +154,10 @@ def _swan_decode_body(meta_ref, q_ref, kv_ref, ki_ref, vv_ref, vi_ref,
     q = q_ref[0, 0].astype(jnp.float32)                        # [G, dh]
 
     # ---- sparse tile ------------------------------------------------------
-    k_dense = expand_packed(kv_ref[0, 0].astype(jnp.float32),
-                            ki_ref[0, 0], dh)                  # [BS, dh]
+    s = gather_scores(q, kv_ref[0, 0].astype(jnp.float32),
+                      ki_ref[0, 0]) * scale                    # [G, BS]
     v_dense = expand_packed(vv_ref[0, 0].astype(jnp.float32),
-                            vi_ref[0, 0], dh)
-
-    s = jax.lax.dot_general(q, k_dense, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
+                            vi_ref[0, 0], dh)                  # [BS, dh]
     if quantized:                     # per-token scales of this head
         s = s * ks_ref[0, 0]                                   # [1, BS]
     t_pos = sb * bs + jax.lax.broadcasted_iota(jnp.int32, (G, bs), 1)

@@ -5,9 +5,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs import SwanConfig
+from repro.core import swan_attention as swa
+from repro.core import winnow
 from repro.kernels.flash_prefill.flash_prefill import flash_attention_pallas
 from repro.kernels.flash_prefill.ref import flash_attention_reference
-from repro.kernels.swan_decode.swan_decode import swan_decode_pallas
+from repro.kernels.flash_prefill.swan_chunk import (
+    swan_chunk_stats_paged_pallas)
+from repro.kernels.swan_decode.swan_decode import (
+    expand_packed, gather_scores, swan_decode_paged_pallas,
+    swan_decode_pallas)
 from repro.kernels.swan_decode.ref import swan_decode_reference
 from repro.kernels.swan_prune.swan_prune import swan_prune_pallas
 from repro.kernels.swan_prune.ref import swan_prune_reference
@@ -75,6 +82,98 @@ def test_swan_decode_kernel_quantized():
     o_r = swan_decode_reference(q, kv8, ki, vv8, vi, bk, bv, bpos, 27, 18,
                                 k_scale=ks, v_scale=vs)
     np.testing.assert_allclose(np.asarray(o_k), np.asarray(o_r), atol=5e-5)
+
+
+@pytest.mark.parametrize("k_active", [16, 32, 64])
+def test_expand_packed_matches_unpack_dense(k_active):
+    """The loop-free tile readers against the dense unpack, with the packed
+    columns past ``k_active`` zeroed as ``topk_pack`` leaves them: the
+    expansion exactly, the gather's scores for G = 8 query rows."""
+    rng = np.random.default_rng(8)
+    x = jnp.asarray(rng.standard_normal((128, 128)), jnp.bfloat16)
+    vals, idx = winnow.topk_pack(x, 64, k_active=jnp.asarray(k_active))
+    vals = vals.astype(jnp.float32)
+    want = winnow.unpack_dense(vals, idx, 128)
+    assert int((want != 0).sum(axis=1).max()) == k_active
+    np.testing.assert_array_equal(np.asarray(expand_packed(vals, idx, 128)),
+                                  np.asarray(want))
+    q = jnp.asarray(rng.standard_normal((8, 128)), jnp.float32)
+    s_ref = jnp.dot(q, want.T, precision=jax.lax.Precision.HIGHEST)
+    np.testing.assert_allclose(np.asarray(gather_scores(q, vals, idx)),
+                               np.asarray(s_ref), rtol=1e-5, atol=1e-4)
+
+
+def _hostile_pool(rng, *, n_pages, Kv, ps, k, dh, quant):
+    """A packed pool side whose pages hold distinct indices, except page 0
+    (trash) and the last page, which hold random int8 indices (negatives
+    and out-of-range ones included) and large values."""
+    shape = (n_pages, Kv, ps, k)
+    idx = np.array(_unique_idx(rng, n_pages, Kv, ps, k, dh))
+    if quant:
+        vals = rng.integers(-127, 128, shape).astype(np.int8)
+    else:
+        vals = rng.standard_normal(shape).astype(np.float32)
+    scale = (rng.random(shape[:-1]) * 0.1 + 0.01).astype(np.float32)
+    for page in (0, n_pages - 1):
+        idx[page] = rng.integers(-128, 128, shape[1:])
+        if quant:
+            scale[page] = 1e4
+        else:
+            vals[page] = 1e4 * np.sign(rng.standard_normal(shape[1:]))
+    side = {"vals": jnp.asarray(vals, jnp.int8 if quant else jnp.bfloat16),
+            "idx": jnp.asarray(idx, jnp.int8)}
+    if quant:
+        side["scale"] = jnp.asarray(scale)
+    return side
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("kernel", ["decode", "chunk"])
+def test_paged_kernels_ignore_dead_and_garbage_tiles(kernel, quant):
+    """Lane 0 reads a full prefix, lane 1 an empty one (every tile the trash
+    page), lane 2 a prefix that ends inside its second page, with a third
+    page mapped past ``sp_len``: the garbage there and on the trash page
+    must leave the output equal to the references on the logical view."""
+    rng = np.random.default_rng(9)
+    B, Kv, G, dh, ps, n_log, k, b = 3, 2, 2, 64, 16, 3, 16, 8
+    n_pages = 1 + 6
+    sides = [_hostile_pool(rng, n_pages=n_pages, Kv=Kv, ps=ps, k=k, dh=dh,
+                           quant=quant) for _ in range(2)]
+    tab = jnp.asarray([[1, 2, 3], [0, 0, 0], [4, 5, n_pages - 1]], jnp.int32)
+    sp = jnp.asarray([n_log * ps, 0, ps + 4], jnp.int32)
+    view = swa.paged_logical_view(
+        {"pool": {"k": sides[0], "v": sides[1]}, "buf_k": None,
+         "buf_v": None, "buf_pos": None}, tab)
+    pool = [sides[0]["vals"], sides[0]["idx"], sides[1]["vals"],
+            sides[1]["idx"]]
+    scales = ({"pool_k_scale": sides[0]["scale"],
+               "pool_v_scale": sides[1]["scale"]} if quant else {})
+    if kernel == "decode":
+        q = _rand(rng, (B, Kv, G, dh), jnp.float32)
+        bk = _rand(rng, (B, Kv, b, dh), jnp.float32)
+        bv = _rand(rng, (B, Kv, b, dh), jnp.float32)
+        bpos = sp[:, None] + jnp.arange(b, dtype=jnp.int32)[None, :]
+        pos = sp + b - 1
+        o_k = swan_decode_paged_pallas(q, *pool, bk, bv, bpos, pos, sp, tab,
+                                       **scales)
+        vk, vv = view["k"], view["v"]
+        o_r = swan_decode_reference(
+            q, vk["vals"], vk["idx"], vv["vals"], vv["idx"], bk, bv, bpos,
+            pos, sp, k_scale=vk.get("scale"), v_scale=vv.get("scale"))
+        assert np.isfinite(np.asarray(o_k)).all()
+        np.testing.assert_allclose(np.asarray(o_k), np.asarray(o_r),
+                                   atol=5e-5)
+        return
+    q = _rand(rng, (B, Kv, 8, dh), jnp.float32)
+    swan = SwanConfig(k_max=k, buffer=b, mode="topk", quantize=quant)
+    got = swan_chunk_stats_paged_pallas(q, *pool, sp, tab, **scales)
+    if not quant:     # stats in f32: bf16 values would round q and p there
+        view = {n: {**view[n], "vals": view[n]["vals"].astype(jnp.float32)}
+                for n in ("k", "v")}
+    want = swa._sparse_stats_bulk(q, view["k"], view["v"], swan, sp, dh)
+    for g, w in zip(got, want):
+        assert np.isfinite(np.asarray(g)).all()
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=5e-5)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
